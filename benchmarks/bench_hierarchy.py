@@ -90,6 +90,9 @@ print(json.dumps({"flat": flat_c, "inner": inner_c, "sync": sync_c}))
 
 def run() -> dict:
     env = dict(os.environ)
+    # a rehearsal on 8 CPU host devices: pinned to the CPU so the child
+    # never reaches for a chip this (JAX-holding) parent may own
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(_CODE)],

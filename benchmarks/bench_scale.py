@@ -323,12 +323,15 @@ _SHARDED_DEVICES = 8
 
 def _spawn_sharded(flag: str, extra=()) -> str:
     """Run ``python -m benchmarks.bench_scale <flag>`` under 8 forced host
-    devices and return its stdout (the --sharded-child prints JSON)."""
+    devices and return its stdout (the --sharded-child prints JSON). The
+    child is pinned to the CPU: it is a host-device rehearsal, and a chip
+    held by this (JAX-holding) parent would hang it."""
     import os
     import subprocess
     import sys
 
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " "
                         "--xla_force_host_platform_device_count="
                         f"{_SHARDED_DEVICES}").strip()

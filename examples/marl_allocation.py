@@ -24,9 +24,10 @@ from repro.core.marl import (DDPGConfig, TrainConfig, act, actor_param_count,
                              compare_with_baselines, observe, train,
                              train_host_loop)
 from repro.core.marl.env import EnvConfig, bs_frequencies
+from repro.launch.runtime import device_info, setup_compile_cache
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--twins", type=int, default=30)
@@ -40,8 +41,12 @@ def main():
                          "controller against an association that drifts "
                          "under the Markov mobility + load-aware kernel "
                          "(repro.core.migration)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def configs(args: argparse.Namespace) -> tuple:
+    """``(EnvConfig, DDPGConfig, TrainConfig)`` of the run ``args`` ask
+    for — the scan trainer's static arguments."""
     from repro.core.migration import MigrationConfig
 
     cfg = EnvConfig(n_twins=args.twins, n_bs=args.bs,
@@ -49,6 +54,16 @@ def main():
                                if args.migration > 0 else None))
     dcfg = DDPGConfig(policy=args.policy)
     tcfg = TrainConfig(steps=args.steps, warmup=min(48, args.steps // 2))
+    return cfg, dcfg, tcfg
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    cache = setup_compile_cache()
+    dev = device_info()
+    print(f"device {dev['platform']} {dev['kind']} x{dev['count']}  "
+          f"compile cache {cache}")
+    cfg, dcfg, tcfg = configs(args)
     key = jax.random.PRNGKey(0)
 
     if args.host_loop:

@@ -84,9 +84,10 @@ def _stamp_carry(ts0: TrainState) -> TrainState:
     replication checker (``sharding.stamp_replicated`` — value-preserving
     pmean/pmax): the checker cannot trace zero-initialized replay /
     optimizer / noise state to a collective, but the scan body returns
-    those leaves psum-derived, and carry tags must match. The four
-    twin-sharded leaves (env data_sizes/assoc, obs.twin_feats,
-    noise.scores) pass through untouched."""
+    those leaves psum-derived, and carry tags must match. Of the four
+    twin-sharded leaves, env data_sizes/assoc and obs.twin_feats pass
+    through untouched; noise.scores starts as shard-local zeros and is
+    tagged varying (``sharding.stamp_varying``) like the body's output."""
     stamp = sharding.stamp_replicated
     return TrainState(
         env=ts0.env._replace(freqs=stamp(ts0.env.freqs),
@@ -97,7 +98,8 @@ def _stamp_carry(ts0: TrainState) -> TrainState:
                         twin_feats=ts0.obs.twin_feats),
         agent=stamp(ts0.agent),
         buf=stamp(ts0.buf),
-        noise=Action(scores=ts0.noise.scores, b_ctl=stamp(ts0.noise.b_ctl),
+        noise=Action(scores=sharding.stamp_varying(ts0.noise.scores),
+                     b_ctl=stamp(ts0.noise.b_ctl),
                      tau=stamp(ts0.noise.tau)),
         key=stamp(ts0.key),
     )
@@ -178,7 +180,7 @@ def train_step(cfg: EnvConfig, dcfg: DDPGConfig, tcfg: TrainConfig,
         return agent, jnp.float32(0.0), jnp.float32(0.0)
 
     # Inside a twin scope, lax.cond cannot branch-match a psum-carrying
-    # update against the constant skip (the 0.4.x replication checker
+    # update against the constant skip (the replication checker
     # rejects the pair), so both branches run and a jnp.where selects —
     # value-identical, and the elementwise rep rule accepts mixed tags.
     # Single-device keeps the work-skipping cond.

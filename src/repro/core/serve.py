@@ -474,9 +474,13 @@ def make_round_step(cfg: EnvConfig, scfg: ServeConfig,
     """The compiled streaming step ``fn(state, keys, row) -> (state',
     metrics)``, donating ``state``. With a multi-shard ``ts`` the body runs
     under a twin scope inside ``shard_map`` (twin leaves sharded per
-    :func:`serve_specs`), still donated at the outer jit."""
+    :func:`serve_specs`), still donated at the outer jit. ``fn.lower``
+    takes the same arguments and returns the ``jax.stages.Lowered`` step,
+    for reading the compiled program."""
     if ts is None or ts.n_shards == 1:
-        return functools.partial(_round_step_jit, cfg, scfg)
+        step = functools.partial(_round_step_jit, cfg, scfg)
+        step.lower = functools.partial(_round_step_jit.lower, cfg, scfg)
+        return step
 
     specs = serve_specs(cfg, scfg)
 
@@ -491,6 +495,8 @@ def make_round_step(cfg: EnvConfig, scfg: ServeConfig,
     def step(state, keys, row, plan=None):
         return jitted(state, keys, row, plan)
 
+    step.lower = lambda state, keys, row, plan=None: jitted.lower(
+        state, keys, row, plan)
     return step
 
 

@@ -41,12 +41,12 @@ Padding convention: a global twin array of length N is padded to
 ``assoc = M`` (out of range — dropped by every segment backend) and zero
 payloads; the :func:`scope` mask excludes them from pooled statistics.
 
-Gradients: regions run with replication checking on (``check_rep`` on the
-jax 0.4.x surface, ``check_vma`` on >= 0.6), under which jax's autodiff
-through ``psum`` is exact — verified against the single-device trainer by
-``tests/test_sharding.py``. The checker cannot statically *prove* the
-resulting parameter gradients replicated, so :func:`pmean_in_scope` stamps
-them with a value-preserving ``pmean`` (see ``repro.core.marl.ddpg``).
+Gradients: regions run with ``jax.shard_map``'s varying-manual-axes check
+on (``check_vma``), under which jax's autodiff through ``psum`` is exact —
+verified against the single-device trainer by ``tests/test_sharding.py``.
+The checker cannot statically *prove* the resulting parameter gradients
+replicated, so :func:`pmean_in_scope` stamps them with a value-preserving
+``pmean`` (see ``repro.core.marl.ddpg``).
 
 Single-device meshes are a no-op fast path: every ``sharded_*`` entry point
 returns the plain function's result untouched, so CPU CI never traces a
@@ -308,6 +308,18 @@ def stamp_replicated(tree):
     return jax.tree_util.tree_map(one, tree)
 
 
+def stamp_varying(tree):
+    """Tag every leaf of a twin-sharded pytree as varying over the twin
+    axis (``lax.pcast(..., to="varying")``, value-preserving) — for scan
+    carries initialized shard-locally from constants (e.g. zero OU noise)
+    whose body output varies per shard. No-op outside a scope."""
+    s = in_scope()
+    if s is None:
+        return tree
+    return jax.tree_util.tree_map(
+        lambda x: jax.lax.pcast(x, s.axis, to="varying"), tree)
+
+
 # ---------------------------------------------------------------------------
 # parity-exact localization of globally-drawn arrays
 # ---------------------------------------------------------------------------
@@ -496,17 +508,10 @@ class TwinSharding:
         return twin_scope(n_global, self.local_n(n_global), self.n_shards)
 
     def shard_map(self, fn, in_specs, out_specs):
-        """Version-portable ``shard_map`` over this mesh with replication
-        checking ON (required for exact autodiff — module docstring).
-        jax >= 0.6 exposes ``jax.shard_map``; 0.4.x uses the experimental
-        module (the same split ``repro.models.moe`` handles)."""
-        if hasattr(jax, "shard_map"):  # jax >= 0.6 surface
-            return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        return _shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+        """``jax.shard_map`` over this mesh with replication checking ON
+        (required for exact autodiff — module docstring)."""
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs)
 
 
 # ---------------------------------------------------------------------------

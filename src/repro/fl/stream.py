@@ -149,14 +149,17 @@ def fl_specs(fcfg: Optional[FLServeConfig]):
 
 
 def fl_init(fcfg: FLServeConfig, key, data, active, *,
-            params=None, malicious=None) -> FLState:
+            params=None, malicious=None, ts=None) -> FLState:
     """Fresh :class:`FLState` at capacity ``active.shape[0]``.
 
     ``data`` is the ``repro.data.cifar10.load`` tuple. ``active`` (the
     serve state's live mask, host or device) seeds the warm-start: live
     twins' buffer rows start at the global model, empty slots at zero.
     ``params`` overrides the global init (e.g. ``DTWNSystem.params`` for
-    parity runs — the system inits from ``PRNGKey(seed)`` too)."""
+    parity runs — the system inits from ``PRNGKey(seed)`` too). With a
+    multi-shard ``ts`` (a ``TwinSharding``; ``active`` is then the padded
+    global mask) the twin buffers are built already split over its mesh,
+    so a state larger than one device never lands on one."""
     mdl = get_model(fcfg.model)
     (x, y), (x_test, y_test), _ = data
     active = np.asarray(active, bool)
@@ -171,17 +174,31 @@ def fl_init(fcfg: FLServeConfig, key, data, active, *,
     if malicious is None:
         malicious = np.zeros(cap, bool)
 
-    def per_twin(p):
-        rows = jnp.broadcast_to(p[None], (cap,) + p.shape)
-        m = active.reshape((-1,) + (1,) * p.ndim)
-        return jnp.where(m, rows, 0.0).astype(p.dtype)
+    def buffers(params, active):
+        def per_twin(p):
+            rows = jnp.broadcast_to(p[None], (cap,) + p.shape)
+            m = active.reshape((-1,) + (1,) * p.ndim)
+            return jnp.where(m, rows, 0.0).astype(p.dtype)
+
+        return (jax.tree_util.tree_map(per_twin, params),
+                jax.tree_util.tree_map(
+                    lambda p: jnp.zeros((cap,) + p.shape, p.dtype), params))
+
+    malicious = jnp.asarray(malicious)
+    if ts is not None and ts.n_shards > 1:
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        twin = NamedSharding(ts.mesh, P(sharding.TWIN_AXIS))
+        buffers = jax.jit(buffers, out_shardings=(twin, twin))
+        malicious = jax.device_put(malicious, twin)
+    twin_params, twin_mom = buffers(params, jnp.asarray(active))
 
     return FLState(
         params=params,
-        twin_params=jax.tree_util.tree_map(per_twin, params),
-        twin_mom=jax.tree_util.tree_map(
-            lambda p: jnp.zeros((cap,) + p.shape, p.dtype), params),
-        malicious=jnp.asarray(malicious),
+        twin_params=twin_params,
+        twin_mom=twin_mom,
+        malicious=malicious,
         x=jnp.asarray(x), y=jnp.asarray(y),
         x_eval=jnp.asarray(x_test[:n_eval]),
         y_eval=jnp.asarray(y_test[:n_eval]))

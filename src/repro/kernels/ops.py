@@ -1,8 +1,8 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute with interpret=True; on a real
-TPU set ``REPRO_PALLAS_INTERPRET=0`` (or rely on the default platform check)
-to compile them natively.
+Off the chip the kernels execute with interpret=True; on a TPU they compile
+natively (``segment_reduce.default_interpret``: ``REPRO_PALLAS_INTERPRET``
+overrides the choice off the chip only).
 """
 from __future__ import annotations
 

@@ -24,10 +24,12 @@ module makes the reduction strategy a first-class, swappable backend:
     boundaries come for free once twins are sorted by BS).
 ``"pallas"``
     The tiled-accumulator kernel: the twin axis streams through VMEM in
-    ``_PALLAS_BLOCK``-sized tiles and an (M, K)-wide fp32 accumulator stays
-    resident across grid steps — per tile it builds the (tile, M)
-    membership mask and contracts it against the value tile on the MXU.
-    One pass over HBM, no serialized scatter. On TPU this compiles as a
+    ``_PALLAS_BLOCK``-sized tiles and, per 128-lane-aligned tile of the
+    payload axis K, an (M, tile)-wide fp32 accumulator stays resident
+    across the twin steps — per tile it builds the (tile, M) membership
+    mask and contracts it against the value tile on the MXU. VMEM use is
+    bounded independently of K (an Eq. 4 CNN leaf is K = 2^21). One pass
+    over HBM, no serialized scatter. On TPU this compiles as a
     native Pallas kernel; on CPU/GPU it executes as the XLA reference
     lowering with *identical tiling* (a ``lax.scan`` over the same twin
     tiles — measured 4-5x faster than the serialized scatter-add on
@@ -81,6 +83,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BACKENDS = ("auto", "pallas", "sort", "segment_sum", "onehot", "sharded")
 
@@ -119,22 +122,41 @@ _TILED_MAX_SEGMENTS = 32
 # Twin-axis tile for the Pallas kernel and its XLA reference lowering:
 # 8 sublanes x 128 lanes of fp32.
 _PALLAS_BLOCK = 1024
+# VMEM bytes of one (twin tile, lane tile) fp32 value block of the Pallas
+# kernel. The payload axis K is tiled in 128-lane multiples to stay under
+# it, so VMEM use is independent of K (a whole CNN layer, K = 2^21, would
+# otherwise need a 250 MB window). Pallas double-buffers each block.
+_PALLAS_TILE_BYTES = 2 * 2**20
+_LANES = 128
 
 
 def default_interpret() -> bool:
-    """Pallas interpret-mode default: native only on real TPUs, overridable
-    via REPRO_PALLAS_INTERPRET. The single source of this convention —
-    repro.kernels.ops delegates here for the other Pallas kernels."""
+    """Pallas interpret-mode default: native on a TPU, the interpreter
+    elsewhere. ``REPRO_PALLAS_INTERPRET`` overrides it off the chip only —
+    asking for the interpreter on a TPU raises, so a chip run can never
+    quietly execute its kernels in interpret mode. The single source of
+    this convention — repro.kernels.ops delegates here for the other
+    Pallas kernels."""
+    on_tpu = jax.default_backend() == "tpu"
     env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    if env is None:
+        return not on_tpu
+    interpret = env not in ("0", "false", "False")
+    if interpret and on_tpu:
+        raise RuntimeError(
+            f"REPRO_PALLAS_INTERPRET={env!r} asks for the Pallas interpreter"
+            f" on a TPU; unset it to run the compiled kernels")
+    return interpret
 
 
 def resolve_backend(n: int, num_segments: int, *, platform=None) -> str:
     """Pick a concrete backend from static shape/platform information.
 
-    TPU -> the Pallas kernel (VMEM-resident accumulator, MXU contraction).
+    TPU -> the Pallas kernel (VMEM-resident accumulator, MXU contraction)
+    at every shape: its grid tiles both the twin axis and the payload axis
+    K, so any (N, K, M) compiles — a per-BS latency sum (K = 1) as well as
+    an Eq. 4 model-leaf aggregation (K = |leaf|, up to 2^21 for the paper
+    CNN's fc1 weight).
     CPU -> dense one-hot while the (N, M) mask fits ``_ONEHOT_BYTES_BUDGET``
     (a single BLAS matmul — the measured CPU winner at small N*M), then the
     tiled pallas lowering while M <= ``_TILED_MAX_SEGMENTS`` (4-5x over the
@@ -209,27 +231,39 @@ def _seg_onehot(values, assoc, num_segments: int):
                          axes=[[0], [0]])
 
 
-def _seg_pallas_kernel(a_ref, v_ref, o_ref, *, num_segments: int):
-    """Grid step i reduces one twin tile into the resident accumulator.
+def _seg_pallas_kernel(a_ref, v_ref, o_ref, *, num_segments: int, n: int,
+                       block_n: int):
+    """Grid step (j, i) reduces twin tile i of lane tile j into the resident
+    accumulator.
 
-    The output BlockSpec maps every grid step to the same (M, K) block, so
-    it stays in VMEM across the sequential grid and accumulates — the
-    standard matmul-k-loop pattern, with the twin axis as the contraction.
+    The output BlockSpec maps every twin step of lane tile j to the same
+    (M, block_k) block, so it stays in VMEM across the sequential twin axis
+    and accumulates — the standard matmul-k-loop pattern, with the twin
+    axis as the contraction. Rows past ``n`` in a ragged last twin tile
+    hold whatever the block window read; they are masked out here, so
+    nothing is padded in HBM.
     """
-    i = pl.program_id(0)
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = a_ref[...]                                    # (block,)
-    v = v_ref[...].astype(jnp.float32)                # (block, K)
-    block = a.shape[0]
-    seg_ids = jax.lax.broadcasted_iota(jnp.int32, (block, num_segments), 1)
-    mask = (a[:, None] == seg_ids).astype(jnp.float32)  # (block, M)
-    # (M, K) partial = mask^T @ v — contraction over the twin tile (MXU).
+    a = a_ref[...]                                    # (block_n, 1)
+    v = v_ref[...].astype(jnp.float32)                # (block_n, block_k)
+    seg_ids = jax.lax.broadcasted_iota(jnp.int32, (a.shape[0], num_segments),
+                                       1)
+    hit = a == seg_ids                                # (block_n, M)
+    if n % block_n:
+        rows = i * block_n + jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+        valid = rows < n
+        hit = hit & valid
+        v = jnp.where(valid, v, 0.0)
+    # (M, block_k) partial = mask^T @ v — contraction over the twin tile
     o_ref[...] += jax.lax.dot_general(
-        mask, v, dimension_numbers=(((0,), (0,)), ((), ())),
+        hit.astype(jnp.float32), v,
+        dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 
@@ -261,41 +295,90 @@ def _seg_tiled_ref(values, assoc, num_segments: int, *,
     return acc
 
 
+def _pallas_blocks(n: int, k: int, block: int) -> tuple:
+    """(twin tile, lane tile) of the Pallas grid. The twin tile is
+    ``block`` (or all of a shorter N); the lane tile is all of K while the
+    value block fits ``_PALLAS_TILE_BYTES``, else the largest multiple of
+    128 lanes that does."""
+    block_n = min(block, n)
+    rows = -(-block_n // 8) * 8                       # sublane-padded
+    max_k = max(_LANES, _PALLAS_TILE_BYTES // (4 * rows) // _LANES * _LANES)
+    return block_n, (k if k <= max_k else max_k)
+
+
+def _pcast_varying(x, vma):
+    missing = tuple(sorted(vma - jax.typeof(x).vma))
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+
+def _seg_pallas_call(values, assoc, num_segments: int, block: int,
+                     interpret: bool):
+    n, k = values.shape
+    block_n, block_k = _pallas_blocks(n, k, block)
+    # ids travel as an (N, 1) column: a 2-D block keeps the TPU tiling
+    # rule (last two block dims divisible by (8, 128) or full) satisfied
+    # when vmap prepends a batch axis
+    a2 = assoc.astype(jnp.int32).reshape(n, 1)
+    # inside a shard_map region the output varies over the mesh axes its
+    # inputs vary over; both inputs are brought to that same set
+    vma = jax.typeof(values).vma | jax.typeof(a2).vma
+    values, a2 = (_pcast_varying(x, vma) for x in (values, a2))
+    return pl.pallas_call(
+        functools.partial(_seg_pallas_kernel, num_segments=num_segments,
+                          n=n, block_n=block_n),
+        grid=(pl.cdiv(k, block_k), pl.cdiv(n, block_n)),
+        in_specs=[
+            pl.BlockSpec((block_n, 1), lambda j, i: (i, 0)),
+            pl.BlockSpec((block_n, block_k), lambda j, i: (i, j)),
+        ],
+        out_specs=pl.BlockSpec((num_segments, block_k), lambda j, i: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((num_segments, k), jnp.float32,
+                                       vma=vma),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(a2, values)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _seg_pallas_diff(values, assoc, num_segments, block, interpret):
+    return _seg_pallas_call(values, assoc, num_segments, block, interpret)
+
+
+def _seg_pallas_fwd(values, assoc, num_segments, block, interpret):
+    return (_seg_pallas_call(values, assoc, num_segments, block, interpret),
+            assoc)
+
+
+def _seg_pallas_bwd(num_segments, block, interpret, assoc, g):
+    # the reduction is linear in values: d values[j] = g[assoc[j]], zero for
+    # dropped (out-of-range) ids
+    ids = jnp.where((assoc >= 0) & (assoc < num_segments), assoc,
+                    num_segments)
+    g0 = jnp.concatenate([g, jnp.zeros_like(g[:1])], axis=0)
+    return jnp.take(g0, ids, axis=0), None
+
+
+_seg_pallas_diff.defvjp(_seg_pallas_fwd, _seg_pallas_bwd)
+
+
 def _seg_pallas(values, assoc, num_segments: int, *, block: int = _PALLAS_BLOCK,
                 interpret=None):
-    """Tiled Pallas reduction: twins stream HBM->VMEM in ``block``-sized
-    tiles, the (M, K) accumulator never leaves VMEM. On non-TPU platforms
-    (unless ``interpret`` is explicitly set) this routes to the XLA
-    reference lowering with identical tiling — the Pallas interpreter is
-    numerics-faithful but far too slow for the hot path."""
+    """Tiled Pallas reduction: twins stream HBM->VMEM in ``block``-row
+    tiles, lane tiles of the payload in parallel, and each (M, block_k)
+    accumulator never leaves VMEM. On non-TPU platforms (unless
+    ``interpret`` is given or ``REPRO_PALLAS_INTERPRET`` is set) this
+    routes to the XLA reference lowering with identical twin tiling — the
+    Pallas interpreter is numerics-faithful but far too slow for the hot
+    path. Differentiable through a custom VJP (the gather ``g[assoc]``)
+    and batchable under ``vmap``."""
     if interpret is None:
-        # honor an explicit REPRO_PALLAS_INTERPRET override (forces the
-        # actual kernel body through the interpreter, as for every other
-        # Pallas kernel); otherwise non-TPU platforms run the XLA reference
-        # lowering with identical tiling.
         if (os.environ.get("REPRO_PALLAS_INTERPRET") is None
                 and jax.default_backend() != "tpu"):
             return _seg_tiled_ref(values, assoc, num_segments, block=block)
         interpret = default_interpret()
-    n, k = values.shape
-    block = min(block, max(n, 1))
-    pad = (-n) % block
-    # pad ids with num_segments: matches no row of the iota, contributes 0.
-    ap = jnp.pad(assoc.astype(jnp.int32), (0, pad),
-                 constant_values=num_segments)
-    vp = jnp.pad(values, ((0, pad), (0, 0)))
-    nb = (n + pad) // block
-    return pl.pallas_call(
-        functools.partial(_seg_pallas_kernel, num_segments=num_segments),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block, k), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((num_segments, k), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_segments, k), jnp.float32),
-        interpret=interpret,
-    )(ap, vp)
+    return _seg_pallas_diff(values, assoc, num_segments, block,
+                            bool(interpret))
 
 
 _IMPLS = {

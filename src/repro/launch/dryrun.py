@@ -38,7 +38,7 @@ import jax.numpy as jnp
 
 from repro.configs import (ARCH_NAMES, SHAPES, get_arch_config,
                            supports_shape)
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.launch.roofline import analytic_memory_bytes, roofline_terms
 from repro.utils.hlo_cost import hlo_cost
 from repro.launch.specs import (cache_shapes, decode_inputs, params_shapes,
@@ -61,6 +61,12 @@ def _sds_with(shardings, tree):
         tree, shardings)
 
 
+def _on_chip() -> bool:
+    """On a TPU a failed compile or analysis fails the run: the CPU-only
+    leniency below exists because XLA-CPU lacks some analyses."""
+    return jax.default_backend() == "tpu"
+
+
 def _mem_analysis(compiled):
     try:
         ma = compiled.memory_analysis()
@@ -74,6 +80,8 @@ def _mem_analysis(compiled):
                 out[field] = int(getattr(ma, field))
         return out
     except Exception as e:  # CPU backend may not implement it
+        if _on_chip():
+            raise
         return {"error": str(e)}
 
 
@@ -85,6 +93,8 @@ def _cost_analysis(compiled):
         return {k: float(v) for k, v in ca.items()
                 if isinstance(v, (int, float))}
     except Exception as e:
+        if _on_chip():
+            raise
         return {"error": str(e)}
 
 
@@ -219,7 +229,8 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     flops_global = cost.dot_flops * n_chips
     coll_global = cost.collective_bytes * n_chips
     rec["roofline"] = roofline_terms(n_chips, flops_global, mem_global,
-                                     coll_global)
+                                     coll_global,
+                                     device_kind=PRODUCTION_DEVICE_KIND)
     # MODEL_FLOPS = 6*N_active*tokens (train) / 2*N_active*tokens (fwd)
     mult = 6.0 if shape.mode == "train" else 2.0
     model_flops = mult * cfg.param_count(active_only=True) * tokens
@@ -295,6 +306,8 @@ def main():
             print(f"  hbm/device={rec['bytes']['hbm_per_device']/1e9:.2f}GB "
                   f"collective/dev={rec['hlo_cost']['collective_bytes_per_device']/1e9:.3f}GB")
         except Exception as e:
+            if _on_chip():
+                raise
             failures += 1
             rec = {"arch": arch, "shape": shape_name, "mesh": mesh_tag,
                    "ok": False, "error": str(e),

@@ -24,7 +24,7 @@ Traffic model (global bytes per step):
 """
 from __future__ import annotations
 
-from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
+from repro.launch.mesh import chip_peaks
 
 
 def analytic_memory_bytes(mode: str, *, params_bytes: float,
@@ -41,10 +41,13 @@ def analytic_memory_bytes(mode: str, *, params_bytes: float,
 
 
 def roofline_terms(n_chips: int, flops_global: float, mem_bytes_global: float,
-                   coll_bytes_global: float) -> dict:
-    compute_s = flops_global / (n_chips * PEAK_FLOPS_BF16)
-    memory_s = mem_bytes_global / (n_chips * HBM_BW)
-    collective_s = coll_bytes_global / (n_chips * ICI_BW_PER_LINK)
+                   coll_bytes_global: float, *, device_kind: str) -> dict:
+    """Roofline seconds of one step on ``n_chips`` chips of ``device_kind``
+    (peaks from ``launch.mesh.CHIP_PEAKS``; an unknown kind raises)."""
+    peaks = chip_peaks(device_kind)
+    compute_s = flops_global / (n_chips * peaks["peak_flops_bf16"])
+    memory_s = mem_bytes_global / (n_chips * peaks["hbm_bw"])
+    collective_s = coll_bytes_global / (n_chips * peaks["ici_bw_per_link"])
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dom = max(terms, key=lambda k: terms[k])

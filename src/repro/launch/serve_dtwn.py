@@ -2,8 +2,9 @@
 
 Runs the :mod:`repro.core.serve` loop — device-resident donated state,
 population churn, pipelined round dispatch — and reports throughput
-(rounds/s) plus streamed round metrics. With ``--shards`` (or on a real
-multi-device backend) the twin axis is sharded via ``core/sharding.py``.
+(rounds/s) plus streamed round metrics. On a multi-device backend the twin
+axis is sharded over every device via ``core/sharding.py``; ``--shards``
+forces that many CPU host devices for a rehearsal without a chip.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.serve_dtwn --capacity 1000 \
@@ -19,9 +20,10 @@ import argparse
 import os
 import sys
 import time
+from typing import Any, Callable, NamedTuple, Optional
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--capacity", type=int, default=1000,
                     help="twin-buffer capacity (= EnvConfig.n_twins)")
@@ -64,16 +66,32 @@ def main(argv=None):
     ap.add_argument("--no-overlap", action="store_true",
                     help="oracle mode: block every round (no pipelining)")
     ap.add_argument("--shards", type=int, default=0,
-                    help="force N host devices for twin sharding; "
-                         "set BEFORE jax imports")
+                    help="force N CPU host devices for twin sharding (a "
+                         "rehearsal without a chip; an error on a TPU)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    if args.shards:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "") +
-            f" --xla_force_host_platform_device_count={args.shards}").strip()
 
+class Service(NamedTuple):
+    """One configured serving stream, as the CLI builds it: the static
+    configs, the compiled donated step, the round keys / knob row / FL
+    plan it consumes, and ``fresh_state()`` for a new donated state."""
+    cfg: Any
+    scfg: Any
+    ts: Any                     # TwinSharding, or None for one device
+    step: Callable
+    keys: Any
+    warm_keys: Any
+    row: Any
+    plan: Any
+    plan1: Any                  # the one-round plan of the warm-up call
+    data: Any
+    fresh_state: Callable[[], Any]
+
+
+def build(args: argparse.Namespace, *, ts=None) -> Service:
+    """Build the stream ``args`` describe. ``ts`` is the twin mesh to
+    shard over (None: one device)."""
     import jax
     import numpy as np
 
@@ -82,8 +100,9 @@ def main(argv=None):
     from repro.core.faults import FaultConfig
     from repro.core.marl.env import EnvConfig
     from repro.core.migration import MigrationConfig
-    from repro.core.sharding import TwinSharding
 
+    if ts is not None and ts.n_shards == 1:
+        ts = None
     cfg = EnvConfig(
         n_twins=args.capacity, n_bs=args.n_bs,
         migration=MigrationConfig() if args.migration else None,
@@ -115,12 +134,9 @@ def main(argv=None):
     row = scenario.knob_row(knobs, 0)
     row_key = batch.key[0]
 
-    ts = TwinSharding.make()
-    sharded = ts.n_shards > 1
-    init = serve.make_serve_init(cfg, scfg, ts=ts if sharded else None,
-                                 n_live=args.live or None)
+    init = serve.make_serve_init(cfg, scfg, ts=ts, n_live=args.live or None)
 
-    plan = data = None
+    plan = plan1 = data = None
     if args.fl:
         from repro.data import cifar10
         from repro.fl import stream as fl_stream
@@ -130,6 +146,7 @@ def main(argv=None):
                                          args.fl_shard_size)
         plan = fl_stream.stream_fl_plan(fcfg, shards, args.rounds,
                                         seed=args.seed)
+        plan1 = jax.tree_util.tree_map(lambda x: x[:1], plan)
 
     def fresh_state():
         st = init(row_key, row)
@@ -138,16 +155,87 @@ def main(argv=None):
                                      jax.random.PRNGKey(args.seed + 1))
         if args.fl:
             fl = fl_stream.fl_init(fcfg, jax.random.PRNGKey(args.seed + 2),
-                                   data, np.asarray(st.active, bool))
+                                   data, np.asarray(st.active, bool), ts=ts)
             st = st._replace(fl=fl)
         return st
 
-    state = fresh_state()
-    step = serve.make_round_step(cfg, scfg, ts=ts if sharded else None)
-    keys = serve.stream_keys(row_key, args.rounds)
+    return Service(
+        cfg=cfg, scfg=scfg, ts=ts,
+        step=serve.make_round_step(cfg, scfg, ts=ts),
+        keys=serve.stream_keys(row_key, args.rounds),
+        warm_keys=serve.stream_keys(jax.random.fold_in(row_key, 99), 1),
+        row=row, plan=plan, plan1=plan1, data=data, fresh_state=fresh_state)
 
+
+def warm_up(svc: Service) -> float:
+    """Compile the donated step off the clock with one throwaway round
+    (donation consumes its state); returns the seconds it took."""
+    import jax
+
+    from repro.core import serve
+
+    t0 = time.perf_counter()
+    warm, m = serve.serve_rounds(svc.cfg, svc.scfg, svc.fresh_state(),
+                                 svc.warm_keys, svc.row, step=svc.step,
+                                 overlap=False, plan=svc.plan1)
+    jax.block_until_ready((warm, m))
+    return time.perf_counter() - t0
+
+
+def run(svc: Service, *, overlap: bool = True):
+    """Serve every round of ``svc.keys`` from a fresh state. Returns
+    ``(final_state, host_metrics, seconds)``, the seconds running from
+    dispatch to the metrics on the host."""
+    from repro.core import serve
+
+    state = svc.fresh_state()
+    t0 = time.perf_counter()
+    state, metrics = serve.serve_rounds(svc.cfg, svc.scfg, state, svc.keys,
+                                        svc.row, step=svc.step,
+                                        overlap=overlap, plan=svc.plan)
+    metrics = serve.stack_metrics(metrics)  # blocks: end of the pipeline
+    return state, metrics, time.perf_counter() - t0
+
+
+def check_metrics(metrics, *, fl: bool) -> Optional[str]:
+    """The run's own sanity gate: None when every streamed round time (and,
+    with FL, loss and accuracy) is finite, else what failed."""
+    import numpy as np
+
+    for k in ("round_time",) + (("fl_loss", "fl_accuracy") if fl else ()):
+        if not np.isfinite(metrics[k]).all():
+            return f"non-finite {k}"
+    return None
+
+
+def main(argv=None):
+    args = parse_args(argv)
+
+    if args.shards:
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "") +
+            f" --xla_force_host_platform_device_count={args.shards}").strip()
+
+    import numpy as np
+
+    from repro.core.sharding import TwinSharding
+    from repro.launch.runtime import device_info, setup_compile_cache
+
+    cache = setup_compile_cache()
+    dev = device_info()
+    if args.shards and dev["platform"] != "cpu":
+        print(f"ERROR: --shards forces CPU host devices; this process runs "
+              f"on {dev['platform']} ({dev['kind']}), where the twin axis "
+              f"already shards over all {dev['count']} devices",
+              file=sys.stderr)
+        return 2
+
+    svc = build(args, ts=TwinSharding.make())
+    n_shards = 1 if svc.ts is None else svc.ts.n_shards
+    print(f"device {dev['platform']} {dev['kind']} x{dev['count']}  "
+          f"compile cache {cache}")
     print(f"serving capacity={args.capacity} live={args.live or args.capacity}"
-          f" bs={args.n_bs} shards={ts.n_shards}"
+          f" bs={args.n_bs} shards={n_shards}"
           f" churn=({args.join},{args.leave}) policy={args.policy or 'static'}"
           f" axes=[{'M' if args.migration else ''}"
           f"{'F' if args.faults else ''}{'C' if args.consensus else ''}"
@@ -157,24 +245,10 @@ def main(argv=None):
         print(f"fl model={args.fl_model} participants="
               f"{args.fl_participants} iters={args.fl_iters} "
               f"batch={args.fl_batch} agg={args.fl_aggregator} "
-              f"data={data[2]}[{data[0][0].shape[0]}]")
+              f"data={svc.data[2]}[{svc.data[0][0].shape[0]}]")
 
-    # warm up the compiled step off the clock (donation needs a throwaway
-    # state — the donated argument is consumed)
-    plan1 = (None if plan is None else
-             jax.tree_util.tree_map(lambda x: x[:1], plan))
-    warm, _ = serve.serve_rounds(cfg, scfg, state, serve.stream_keys(
-        jax.random.fold_in(row_key, 99), 1), row, step=step, overlap=False,
-        plan=plan1)
-    state = fresh_state()
-
-    t0 = time.time()
-    state, metrics = serve.serve_rounds(cfg, scfg, state, keys, row,
-                                        step=step,
-                                        overlap=not args.no_overlap,
-                                        plan=plan)
-    metrics = serve.stack_metrics(metrics)  # blocks: end of the pipeline
-    dt = time.time() - t0
+    print(f"warm-up (compile) {warm_up(svc):.2f}s")
+    _, metrics, dt = run(svc, overlap=not args.no_overlap)
 
     rt = metrics["round_time"]
     print(f"{args.rounds} rounds in {dt:.2f}s wall "
@@ -197,11 +271,9 @@ def main(argv=None):
               f"{float(np.mean(metrics['fl_n_participants'])):.1f}  "
               f"accept_frac mean="
               f"{float(np.mean(metrics['fl_accept_frac'])):.3f}")
-        if not (np.isfinite(fll).all() and np.isfinite(fla).all()):
-            print("ERROR: non-finite FL metrics", file=sys.stderr)
-            return 1
-    if not np.isfinite(rt).all():
-        print("ERROR: non-finite round times", file=sys.stderr)
+    err = check_metrics(metrics, fl=args.fl)
+    if err is not None:
+        print(f"ERROR: {err}", file=sys.stderr)
         return 1
     return 0
 

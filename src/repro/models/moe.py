@@ -177,7 +177,6 @@ def moe_capacity_ep_a2a(cfg, p, x):
     E_loc = E // n_sh
     B, S, d = x.shape
     k = cfg.moe_top_k
-    ff_psum_axes = ()  # set by the old-jax fully-manual branch below
 
     def local_fn(xb, router, wg, wu, wd):
         with manual_axes(man):
@@ -214,14 +213,11 @@ def moe_capacity_ep_a2a(cfg, p, x):
                                   tiled=True)  # (n_src, E_loc, C_loc, d)
         xe = jnp.transpose(recv, (1, 0, 2, 3)).reshape(E_loc, n_sh * C_loc, d)
 
-        # ---- local expert compute (model axis auto-sharded on ff, or
-        # manually ff-sharded + psum'd on the old-jax fallback path) ----
+        # ---- local expert compute (model axis auto-sharded on ff) ----
         h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, wg))
         h = h * jnp.einsum("ecd,edf->ecf", xe, wu)
         h = constrain(h, None, None, "model")
         ye = jnp.einsum("ecf,efd->ecd", h, wd)  # (E_loc, n_sh*C_loc, d)
-        if ff_psum_axes:
-            ye = jax.lax.psum(ye, ff_psum_axes)
 
         # ---- return path: inverse all_to_all ----
         y4 = jnp.transpose(ye.reshape(E_loc, n_sh, C_loc, d), (1, 0, 2, 3))
@@ -233,38 +229,13 @@ def moe_capacity_ep_a2a(cfg, p, x):
         return out.astype(xb.dtype).reshape(B_loc, S, d), aux
 
     P = jax.sharding.PartitionSpec
-    man_spec = man_axes
-    in_specs = (P(man_spec, None, None), P(None, None),
-                P(man_spec, None, None), P(man_spec, None, None),
-                P(man_spec, None, None))
-    out_specs = (P(man_spec, None, None), P())
-    if hasattr(jax, "shard_map"):  # jax >= 0.6 surface
-        fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False,
-                           axis_names=set(man))
-    else:
-        # jax 0.4.x: all_to_all inside a partial-auto shard_map trips an
-        # SPMD-partitioner manual-subgroup check, so the whole mesh goes
-        # MANUAL here. The ff ("model") axes lose their GSPMD auto-sharding;
-        # when ff divides the leftover axes, shard the expert weights' ff
-        # dim explicitly and psum the down-projection contraction
-        # (ff_psum_axes above); otherwise replicate the expert weights.
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        rest = tuple(a for a in mesh.axis_names if a not in set(man))
-        rest_size = 1
-        for a in rest:
-            rest_size *= mesh.shape[a]
-        ff = p["wg"].shape[-1]
-        if rest and ff % rest_size == 0:
-            ff_psum_axes = rest if len(rest) > 1 else rest[0]
-            rest_spec = rest if len(rest) > 1 else rest[0]
-            in_specs = (in_specs[0], in_specs[1],
-                        P(man_spec, None, rest_spec),
-                        P(man_spec, None, rest_spec),
-                        P(man_spec, rest_spec, None))
-        fn = _shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    in_specs = (P(man_axes, None, None), P(None, None),
+                P(man_axes, None, None), P(man_axes, None, None),
+                P(man_axes, None, None))
+    out_specs = (P(man_axes, None, None), P())
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False,
+                       axis_names=set(man))
     out, aux = fn(x, p["router"], p["wg"], p["wu"], p["wd"])
     if cfg.n_shared_experts:
         # shared experts run OUTSIDE the manual region: their weights are

@@ -100,12 +100,6 @@ def constrain(x, *parts):
             continue
         manual = _manual()
         if manual:
-            if not hasattr(jax, "shard_map"):
-                # jax 0.4.x partial-auto shard_map: a with_sharding_constraint
-                # inside the manual region trips an SPMD-partitioner manual-
-                # subgroup check. The constraint is only a propagation hint
-                # for the auto axes, so drop it and let GSPMD decide.
-                return x
             ax_t = (ax,) if isinstance(ax, str) else tuple(ax)
             ax_t = tuple(a for a in ax_t if a not in manual)
             if not ax_t:

@@ -27,7 +27,9 @@ def test_ep_a2a_matches_dense_oracle_and_grads():
         toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
                                   cfg.vocab_size)
         ref, _ = m_dense.forward(params, {"tokens": toks})
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_debug_mesh
+
+        mesh = make_debug_mesh(8)  # (4, 2) ("data", "model"), Auto axes
         params_s = jax.device_put(
             params, to_shardings(param_pspecs(params, mesh), mesh))
         toks_s = jax.device_put(

@@ -211,7 +211,6 @@ def test_sharded_segment_reduce_direct_8_devices():
     scope."""
     code = """
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.sharding import TwinSharding
         from repro.kernels.segment_reduce import segment_reduce
