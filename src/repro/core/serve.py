@@ -71,6 +71,13 @@ __all__ = [
 _CHURN_FOLD = 11    # per-round join/leave draws
 _DYNAMICS_FOLD = 12  # per-round channel/frequency evolution (opt-in)
 
+# host spans of serve_rounds, written into the profiler's trace while one
+# is recording: a round's input slicing, its step's enqueue, and the
+# per-metric stacking at the end of a call
+SPAN_INPUTS = "serve.inputs"
+SPAN_ENQUEUE = "serve.enqueue"
+SPAN_STACK = "serve.stack"
+
 
 @dataclasses.dataclass(frozen=True)
 class ServeConfig:
@@ -300,34 +307,40 @@ def _round_step(cfg: EnvConfig, scfg: ServeConfig, state: ServeState,
     bodies bitwise at a fixed full population (see module docstring):
     migration -> faults -> Eq. 17 scoring -> chain round -> FL round
     (``scfg.fl``; ``plan`` is that round's :class:`~repro.fl.stream.FLPlan`
-    row) -> churn -> (optional) dynamics. Returns ``(state', metrics)``."""
+    row) -> churn -> (optional) dynamics. Each stage runs under a
+    ``jax.named_scope`` (``association``, ``migration``, ``faults``,
+    ``price``, ``chain``, ``fl_round``, ``churn``, ``dynamics``, ``replay``),
+    which names its ops in a profiler trace. Returns ``(state', metrics)``."""
     st = state.env
     m = cfg.n_bs
     active = state.active
 
     # --- association + controls for this round ---
-    if scfg.policy is not None:
-        from repro.core.marl.ddpg import act
+    with jax.named_scope("association"):
+        if scfg.policy is not None:
+            from repro.core.marl.ddpg import act
 
-        obs = env_mod.observe(cfg, st)
-        a = act(cfg, state.agent, obs, policy=scfg.policy)
-        assoc_cmd, b, tau = env_mod.decode_actions(cfg, a)
-        assoc_cmd = jnp.where(active, assoc_cmd, m)
-        b = jnp.where(active, b, 0.0)
-    else:
-        obs = a = None
-        assoc_cmd = st.assoc
-        b = jnp.where(active, 0.5, 0.0)
-        tau = jnp.full((m, cfg.wl.n_subchannels), 1.0 / m)
-    up = comms.uplink_rate(cfg.wl, tau, st.h_up, st.dist)
-    down = comms.downlink_rate(cfg.wl, st.h_down, st.dist)
+            obs = env_mod.observe(cfg, st)
+            a = act(cfg, state.agent, obs, policy=scfg.policy)
+            assoc_cmd, b, tau = env_mod.decode_actions(cfg, a)
+            assoc_cmd = jnp.where(active, assoc_cmd, m)
+            b = jnp.where(active, b, 0.0)
+        else:
+            obs = a = None
+            assoc_cmd = st.assoc
+            b = jnp.where(active, 0.5, 0.0)
+            tau = jnp.full((m, cfg.wl.n_subchannels), 1.0 / m)
+        up = comms.uplink_rate(cfg.wl, tau, st.h_up, st.dist)
+        down = comms.downlink_rate(cfg.wl, st.h_down, st.dist)
 
     # --- migration (fold-3 round key; _migration_one's body) ---
     if cfg.migration is not None:
-        assoc = migration.migration_step(cfg.migration, keys.mig, assoc_cmd,
-                                         st.data_sizes, m)
-        # the kernel migrates every row; re-stamp inactive rows out of range
-        assoc = jnp.where(active, assoc, m)
+        with jax.named_scope("migration"):
+            assoc = migration.migration_step(cfg.migration, keys.mig,
+                                             assoc_cmd, st.data_sizes, m)
+            # the kernel migrates every row; re-stamp inactive rows out of
+            # range
+            assoc = jnp.where(active, assoc, m)
     else:
         assoc = assoc_cmd
 
@@ -335,40 +348,42 @@ def _round_step(cfg: EnvConfig, scfg: ServeConfig, state: ServeState,
     # slowdowns are exactly 1.0 and the gate is the identity, so one body
     # serves every axis combination bitwise) ---
     if cfg.faults is not None:
-        k_slow, k_out = jax.random.split(keys.fault)
-        slow = faults_mod.straggler_slowdowns(cfg.faults, k_slow,
-                                              st.data_sizes.shape[0],
-                                              rate=row.straggler)
-        bad = faults_mod.outage_step(cfg.faults, k_out, state.bad,
-                                     rate=row.outage)
-        up_eff = faults_mod.outage_gate(cfg.faults, up, bad)
-        b_eff = b * slow
+        with jax.named_scope("faults"):
+            k_slow, k_out = jax.random.split(keys.fault)
+            slow = faults_mod.straggler_slowdowns(cfg.faults, k_slow,
+                                                  st.data_sizes.shape[0],
+                                                  rate=row.straggler)
+            bad = faults_mod.outage_step(cfg.faults, k_out, state.bad,
+                                         rate=row.outage)
+            up_eff = faults_mod.outage_gate(cfg.faults, up, bad)
+            b_eff = b * slow
     else:
         slow, bad, up_eff, b_eff = None, state.bad, up, b
 
     # --- Eq. 17 scoring: the same max+max+block decomposition every batch
     # runner uses (latency.round_time's internal composition) ---
-    cmp_max = jnp.max(latency.t_cmp(cfg.lat, assoc, b_eff, st.data_sizes,
-                                    st.freqs))
-    bc_max = jnp.max(latency.t_broadcast(cfg.lat, assoc, up_eff, m))
-    if cfg.consensus is not None:
-        qf = jnp.round(jnp.asarray(row.quorum,
-                                   jnp.float32)).astype(jnp.int32)
-        t_block = consensus_mod.consensus_time(
-            cfg.lat, cfg.consensus, down, st.freqs, quorum_f=qf,
-            byz_frac=row.byzantine, block_size_bits=row.block_size)
-    else:
-        t_block = latency.t_block_validation(cfg.lat, down, st.freqs)
-    t_round = cmp_max + bc_max + t_block
+    with jax.named_scope("price"):
+        cmp_max = jnp.max(latency.t_cmp(cfg.lat, assoc, b_eff, st.data_sizes,
+                                        st.freqs))
+        bc_max = jnp.max(latency.t_broadcast(cfg.lat, assoc, up_eff, m))
+        if cfg.consensus is not None:
+            qf = jnp.round(jnp.asarray(row.quorum,
+                                       jnp.float32)).astype(jnp.int32)
+            t_block = consensus_mod.consensus_time(
+                cfg.lat, cfg.consensus, down, st.freqs, quorum_f=qf,
+                byz_frac=row.byzantine, block_size_bits=row.block_size)
+        else:
+            t_block = latency.t_block_validation(cfg.lat, down, st.freqs)
+        t_round = cmp_max + bc_max + t_block
 
     # --- chain round (fold-8 round key; _consensus_one's body) ---
     chain = st.chain
     accept = None
     if cfg.consensus is not None:
-        occ = latency.twin_counts(assoc, m)
-        chain, _, accept = consensus_mod.chain_round(cfg.consensus, chain,
-                                                     keys.chain, state.byz,
-                                                     occ)
+        with jax.named_scope("chain"):
+            occ = latency.twin_counts(assoc, m)
+            chain, _, accept = consensus_mod.chain_round(
+                cfg.consensus, chain, keys.chain, state.byz, occ)
 
     # --- streamed FL round (``scfg.fl``): vmapped local SGD over the
     # planned participants, Eq. 4/5 + verify gate on device — trains the
@@ -379,9 +394,10 @@ def _round_step(cfg: EnvConfig, scfg: ServeConfig, state: ServeState,
     if scfg.fl is not None:
         from repro.fl import stream as fl_stream
 
-        fl_state, fl_metrics = fl_stream.fl_round(
-            scfg.fl, state.fl, plan, active=active,
-            data_sizes=st.data_sizes, assoc=assoc, n_bs=m)
+        with jax.named_scope("fl_round"):
+            fl_state, fl_metrics = fl_stream.fl_round(
+                scfg.fl, state.fl, plan, active=active,
+                data_sizes=st.data_sizes, assoc=assoc, n_bs=m)
 
     # --- churn (fold-11 round key — a fresh stream, so churn-off serving
     # consumes exactly the batch runners' draws and nothing else) ---
@@ -390,21 +406,24 @@ def _round_step(cfg: EnvConfig, scfg: ServeConfig, state: ServeState,
     assoc_next = assoc
     n_joined = n_left = jnp.int32(0)
     if scfg.churns:
-        active, data, assoc_next, n_joined, n_left = churn_step(
-            cfg, scfg, keys.churn, active, data, assoc, row)
-        if scfg.fl is not None:
-            from repro.fl import stream as fl_stream
+        with jax.named_scope("churn"):
+            active, data, assoc_next, n_joined, n_left = churn_step(
+                cfg, scfg, keys.churn, active, data, assoc, row)
+            if scfg.fl is not None:
+                from repro.fl import stream as fl_stream
 
-            # model-buffer churn contract: admitted rows warm-start from
-            # the round's NEW global model, evicted rows go to padding
-            fl_state = fl_stream.fl_churn_update(
-                fl_state, active & ~pre_active, pre_active & ~active)
+                # model-buffer churn contract: admitted rows warm-start
+                # from the round's NEW global model, evicted rows go to
+                # padding
+                fl_state = fl_stream.fl_churn_update(
+                    fl_state, active & ~pre_active, pre_active & ~active)
 
     # --- optional between-round dynamics (fold-12 round key) ---
     env2 = st._replace(data_sizes=data, assoc=assoc_next, chain=chain,
                        t=st.t + 1)
     if scfg.evolve_channels:
-        env2 = env_mod.env_evolve(cfg, env2, keys.dyn)
+        with jax.named_scope("dynamics"):
+            env2 = env_mod.env_evolve(cfg, env2, keys.dyn)
 
     state2 = ServeState(env=env2, active=active, bad=bad, byz=state.byz,
                         agent=state.agent, buf=state.buf, fl=fl_state,
@@ -415,29 +434,33 @@ def _round_step(cfg: EnvConfig, scfg: ServeConfig, state: ServeState,
     if scfg.policy is not None and state.buf is not None:
         from repro.core.marl import replay, spaces
 
-        reward = jnp.full((m,), -t_round) * cfg.reward_scale
-        enc = spaces.encode_action(cfg, a, obs.twin_feats)
-        s2 = spaces.compact_obs(env_mod.observe(cfg, env2))
-        state2 = state2._replace(buf=replay.replay_add(
-            state.buf, spaces.compact_obs(obs), enc, reward, s2))
+        with jax.named_scope("replay"):
+            reward = jnp.full((m,), -t_round) * cfg.reward_scale
+            enc = spaces.encode_action(cfg, a, obs.twin_feats)
+            s2 = spaces.compact_obs(env_mod.observe(cfg, env2))
+            state2 = state2._replace(buf=replay.replay_add(
+                state.buf, spaces.compact_obs(obs), enc, reward, s2))
 
     metrics = {"round_time": t_round,
                "n_active": sharding.twin_count(state2.active),
                "n_joined": n_joined, "n_left": n_left}
     metrics.update(fl_metrics)
     if cfg.faults is not None:
-        metrics["straggler_frac"] = faults_mod.straggler_frac(slow)
-        metrics["outage_frac"] = jnp.mean(bad.astype(jnp.float32))
+        with jax.named_scope("faults"):
+            metrics["straggler_frac"] = faults_mod.straggler_frac(slow)
+            metrics["outage_frac"] = jnp.mean(bad.astype(jnp.float32))
     if cfg.migration is not None:
-        load = assoc_mod.bs_loads(assoc, st.data_sizes, m)
-        metrics["migration_rate"] = migration.migration_rate(assoc_cmd,
-                                                             assoc)
-        metrics["imbalance"] = load["imbalance"]
+        with jax.named_scope("migration"):
+            load = assoc_mod.bs_loads(assoc, st.data_sizes, m)
+            metrics["migration_rate"] = migration.migration_rate(assoc_cmd,
+                                                                 assoc)
+            metrics["imbalance"] = load["imbalance"]
     if cfg.consensus is not None:
         metrics["accept_frac"] = accept
         metrics["consensus_time"] = t_block
-        metrics["honest_stake_share"] = consensus_mod.honest_stake_share(
-            chain, state.byz)
+        with jax.named_scope("chain"):
+            metrics["honest_stake_share"] = consensus_mod.honest_stake_share(
+                chain, state.byz)
     return state2, metrics
 
 
@@ -533,20 +556,22 @@ def serve_rounds(cfg: EnvConfig, scfg: ServeConfig, state: ServeState,
     if scfg.fl is not None and plan is None:
         raise ValueError("ServeConfig.fl is set — serve_rounds needs the "
                          "stream's FLPlan (see fl.stream.stream_fl_plan)")
+    from repro.fl.stream import plan_row
+
     out = []
     for t in range(keys.fault.shape[0]):
-        if plan is None:
-            state, m = step(state, round_keys(keys, t), _row_t(rows, t))
-        else:
-            from repro.fl.stream import plan_row
-
-            state, m = step(state, round_keys(keys, t), _row_t(rows, t),
-                            plan_row(plan, t))
+        with jax.profiler.TraceAnnotation(SPAN_INPUTS):
+            args = (round_keys(keys, t), _row_t(rows, t))
+            if plan is not None:
+                args += (plan_row(plan, t),)
+        with jax.profiler.TraceAnnotation(SPAN_ENQUEUE):
+            state, m = step(state, *args)
         if not overlap:
             state = jax.block_until_ready(state)
             m = jax.block_until_ready(m)
         out.append(m)
-    return state, {k: jnp.stack([m[k] for m in out]) for k in out[0]}
+    with jax.profiler.TraceAnnotation(SPAN_STACK):
+        return state, {k: jnp.stack([m[k] for m in out]) for k in out[0]}
 
 
 def stack_metrics(metrics) -> dict:

@@ -339,86 +339,98 @@ def fl_round(fcfg: FLServeConfig, fl: FLState, plan: FLPlan, *,
                          x_eval=sharding.stamp_replicated(fl.x_eval),
                          y_eval=sharding.stamp_replicated(fl.y_eval))
         plan = sharding.stamp_replicated(plan)
-    u = plan.users
-    part = plan.valid & sharding.twin_gather(active, u, fill=False)
-    mal = part & sharding.twin_gather(fl.malicious, u, fill=False)
-    w_u = jnp.where(part, sharding.twin_gather(data_sizes, u, fill=0.0), 0.0)
-    assoc_u = jnp.where(part, sharding.twin_gather(assoc, u, fill=n_bs),
-                        n_bs).astype(jnp.int32)
+    with jax.named_scope("gather"):
+        u = plan.users
+        part = plan.valid & sharding.twin_gather(active, u, fill=False)
+        mal = part & sharding.twin_gather(fl.malicious, u, fill=False)
+        w_u = jnp.where(part, sharding.twin_gather(data_sizes, u, fill=0.0),
+                        0.0)
+        assoc_u = jnp.where(part, sharding.twin_gather(assoc, u, fill=n_bs),
+                            n_bs).astype(jnp.int32)
 
-    # pre-gathered minibatches: (P, L, B, ...) — both attacks train on
-    # flipped labels (fl.client law); model_replacement also boosts below
-    xb = jnp.take(fl.x, plan.batch, axis=0)
-    yb = jnp.take(fl.y, plan.batch, axis=0)
-    if sharding.in_scope() is not None:
-        # the dataset itself stays unstamped (stamping it would pmean the
-        # full training set every round) — stamp the per-round gathers
-        xb = sharding.stamp_replicated(xb)
-        yb = sharding.stamp_replicated(yb)
-    yb = jnp.where(mal[:, None, None], client_mod.flip_labels(yb), yb)
+        # pre-gathered minibatches: (P, L, B, ...) — both attacks train on
+        # flipped labels (fl.client law); model_replacement also boosts
+        # below
+        xb = jnp.take(fl.x, plan.batch, axis=0)
+        yb = jnp.take(fl.y, plan.batch, axis=0)
+        if sharding.in_scope() is not None:
+            # the dataset itself stays unstamped (stamping it would pmean
+            # the full training set every round) — stamp the per-round
+            # gathers
+            xb = sharding.stamp_replicated(xb)
+            yb = sharding.stamp_replicated(yb)
+        yb = jnp.where(mal[:, None, None], client_mod.flip_labels(yb), yb)
 
     def train_one(xs, ys):
         p, s, losses = client_mod.local_sgd(mdl.loss_fn, opt, fl.params,
                                             xs, ys)
         return p, s["mom"], losses[-1]
 
-    p_new, mom_new, _ = jax.vmap(train_one)(xb, yb)
-    if fcfg.attack == "model_replacement":
-        boost = jnp.where(mal, fcfg.attack_boost, 1.0)
+    with jax.named_scope("local_sgd"):
+        p_new, mom_new, _ = jax.vmap(train_one)(xb, yb)
+        if fcfg.attack == "model_replacement":
+            boost = jnp.where(mal, fcfg.attack_boost, 1.0)
 
-        def replace(old, new):
-            b = boost.reshape((-1,) + (1,) * old.ndim)
-            return old[None] + b * (new - old[None])
+            def replace(old, new):
+                b = boost.reshape((-1,) + (1,) * old.ndim)
+                return old[None] + b * (new - old[None])
 
-        p_new = jax.tree_util.tree_map(replace, fl.params, p_new)
+            p_new = jax.tree_util.tree_map(replace, fl.params, p_new)
 
     # scatter trained rows into the twin buffers (dropped participants ->
     # sentinel -1 -> no write); aggregation then runs over the capacity
     # axis so the sharded segment-reduce sees each row exactly once
-    rows = jnp.where(part, u, -1)
-    twin_params = jax.tree_util.tree_map(
-        lambda buf, r: sharding.twin_scatter_rows(buf, rows, r),
-        fl.twin_params, p_new)
-    twin_mom = jax.tree_util.tree_map(
-        lambda buf, r: sharding.twin_scatter_rows(buf, rows, r),
-        fl.twin_mom, mom_new)
-    w_cap = sharding.twin_scatter_rows(jnp.zeros_like(data_sizes), rows, w_u)
-    assoc_cap = sharding.twin_scatter_rows(
-        jnp.full(data_sizes.shape, n_bs, jnp.int32), rows, assoc_u)
+    with jax.named_scope("scatter"):
+        rows = jnp.where(part, u, -1)
+        twin_params = jax.tree_util.tree_map(
+            lambda buf, r: sharding.twin_scatter_rows(buf, rows, r),
+            fl.twin_params, p_new)
+        twin_mom = jax.tree_util.tree_map(
+            lambda buf, r: sharding.twin_scatter_rows(buf, rows, r),
+            fl.twin_mom, mom_new)
+        w_cap = sharding.twin_scatter_rows(jnp.zeros_like(data_sizes), rows,
+                                           w_u)
+        assoc_cap = sharding.twin_scatter_rows(
+            jnp.full(data_sizes.shape, n_bs, jnp.int32), rows, assoc_u)
 
     # --- Eq. 4 (per-BS), plain or robust ---
-    if fcfg.aggregator == "fedavg":
-        per_bs, bs_w = hierarchy.bs_aggregate_stacked(
-            twin_params, w_cap, assoc_cap, n_bs)
-        n_cli = n_sus = None
-    else:
-        per_bs, bs_w, survivor = faults_mod.robust_bs_aggregate_stacked(
-            twin_params, w_cap, assoc_cap, n_bs,
-            aggregator=fcfg.aggregator, trim_k=fcfg.trim_k,
-            krum_f=fcfg.krum_f)
-        n_cli, n_sus = faults_mod.suspect_counts(survivor, assoc_cap, n_bs)
+    with jax.named_scope("eq4"):
+        if fcfg.aggregator == "fedavg":
+            per_bs, bs_w = hierarchy.bs_aggregate_stacked(
+                twin_params, w_cap, assoc_cap, n_bs)
+            n_cli = n_sus = None
+        else:
+            per_bs, bs_w, survivor = faults_mod.robust_bs_aggregate_stacked(
+                twin_params, w_cap, assoc_cap, n_bs,
+                aggregator=fcfg.aggregator, trim_k=fcfg.trim_k,
+                krum_f=fcfg.krum_f)
+            n_cli, n_sus = faults_mod.suspect_counts(survivor, assoc_cap,
+                                                     n_bs)
 
     # --- chain verify gate on the fixed holdout slice ---
     eval_batch = {"images": fl.x_eval, "labels": fl.y_eval}
     submitted = bs_w > 0.0
     if fcfg.verify:
-        bs_losses = jax.vmap(lambda prm: mdl.loss_fn(prm, eval_batch))(
-            per_bs)
-        accept = consensus_mod.verify_metas(
-            bs_losses, submitted, tolerance=fcfg.tolerance,
-            n_clients=n_cli, n_suspect=n_sus)
+        with jax.named_scope("verify"):
+            bs_losses = jax.vmap(lambda prm: mdl.loss_fn(prm, eval_batch))(
+                per_bs)
+            accept = consensus_mod.verify_metas(
+                bs_losses, submitted, tolerance=fcfg.tolerance,
+                n_clients=n_cli, n_suspect=n_sus)
     else:
         accept = submitted
 
     # --- Eq. 5 over accepted BSs; keep the old global when none pass ---
-    agg = hierarchy.global_aggregate_stacked(
-        per_bs, bs_w, accept, weighted_global=fcfg.weighted_global)
-    any_acc = jnp.any(accept)
-    params = jax.tree_util.tree_map(
-        lambda old, new: jnp.where(any_acc, new, old), fl.params, agg)
+    with jax.named_scope("eq5"):
+        agg = hierarchy.global_aggregate_stacked(
+            per_bs, bs_w, accept, weighted_global=fcfg.weighted_global)
+        any_acc = jnp.any(accept)
+        params = jax.tree_util.tree_map(
+            lambda old, new: jnp.where(any_acc, new, old), fl.params, agg)
 
-    loss = mdl.loss_fn(params, eval_batch)
-    acc = mdl.accuracy(params, eval_batch)
+    with jax.named_scope("eval"):
+        loss = mdl.loss_fn(params, eval_batch)
+        acc = mdl.accuracy(params, eval_batch)
     fl2 = fl._replace(params=params, twin_params=twin_params,
                       twin_mom=twin_mom)
     metrics = {

@@ -337,6 +337,7 @@ def _seg_pallas_call(values, assoc, num_segments: int, block: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="segment_reduce",
     )(a2, values)
 
 

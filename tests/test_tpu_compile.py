@@ -15,6 +15,7 @@ one process may hold the TPU library, and several test workers import
 this file.
 """
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +80,17 @@ def test_segment_reduce_compiles_for_v5e(one_chip, n, k, m):
                                        interpret=False),
         _sds(one_chip, (n, k)), _sds(one_chip, (n,), jnp.int32))
     assert "tpu_custom_call" in txt
+
+
+def test_segment_reduce_kernel_is_named_on_v5e(one_chip):
+    """The kernel's custom call is named ``segment_reduce`` in the compiled
+    program, so its events in a chip trace carry that name."""
+    txt = _compile_text(
+        lambda v, a: sr.segment_reduce(v, a, 5, backend="pallas",
+                                       interpret=False),
+        _sds(one_chip, (1000, 4)), _sds(one_chip, (1000,), jnp.int32))
+    calls = re.findall(r"%([\w.\-]+) = \S+ custom-call\(.*tpu_custom_call", txt)
+    assert calls and all(c.startswith("segment_reduce") for c in calls), calls
 
 
 @pytest.mark.parametrize("model,n", [(cnn, 100), (tiny, 10_000)],
