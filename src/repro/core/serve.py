@@ -46,6 +46,7 @@ from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import association as assoc_mod
@@ -72,8 +73,8 @@ _CHURN_FOLD = 11    # per-round join/leave draws
 _DYNAMICS_FOLD = 12  # per-round channel/frequency evolution (opt-in)
 
 # host spans of serve_rounds, written into the profiler's trace while one
-# is recording: a round's input slicing, its step's enqueue, and the
-# per-metric stacking at the end of a call
+# is recording: a round's input selection, its step's enqueue, and the
+# metric stacking at the end of a call
 SPAN_INPUTS = "serve.inputs"
 SPAN_ENQUEUE = "serve.enqueue"
 SPAN_STACK = "serve.stack"
@@ -535,6 +536,28 @@ def _row_t(rows: StreamKnobs, t: int) -> StreamKnobs:
         lambda x: x[t] if jnp.ndim(x) else x, rows)
 
 
+@jax.jit
+def _round_inputs(keys: RoundKeys, rows: StreamKnobs, plan, t):
+    """Round ``t``'s step inputs as one compiled program: ``(round_keys,
+    _row_t, plan_row)`` of the call's stacks (the plan's row only when a
+    plan is given). ``t`` is traced, so the program compiles once per
+    input shape, whatever the round; a traced index selects the same rows,
+    bit for bit, as the eager ``x[t]``."""
+    args = (round_keys(keys, t), _row_t(rows, t))
+    if plan is None:
+        return args
+    from repro.fl.stream import plan_row
+
+    return args + (plan_row(plan, t),)
+
+
+@jax.jit
+def _stack_rounds(out):
+    """A call's per-round metric dicts, stacked leaf by leaf into
+    ``(n_rounds,)`` arrays as one compiled program (one per ``n_rounds``)."""
+    return {k: jnp.stack([m[k] for m in out]) for k in out[0]}
+
+
 def serve_rounds(cfg: EnvConfig, scfg: ServeConfig, state: ServeState,
                  keys: RoundKeys, rows: StreamKnobs, *, step=None,
                  overlap: bool = True, ts: Optional[TwinSharding] = None,
@@ -550,32 +573,47 @@ def serve_rounds(cfg: EnvConfig, scfg: ServeConfig, state: ServeState,
     :class:`~repro.fl.stream.FLPlan` (required when ``scfg.fl`` is set),
     consumed one row per round like ``keys``/``rows``. Returns
     ``(final_state, metrics)`` with metrics stacked (n_rounds,) device
-    arrays (see :func:`stack_metrics` for host conversion)."""
+    arrays (see :func:`stack_metrics` for host conversion).
+
+    The host dispatches two programs a round, the input selection
+    (:func:`_round_inputs`) and the step, and one more a call, the metric
+    stack (:func:`_stack_rounds`); it issues no eager array op."""
     if step is None:
         step = make_round_step(cfg, scfg, ts)
     if scfg.fl is not None and plan is None:
         raise ValueError("ServeConfig.fl is set — serve_rounds needs the "
                          "stream's FLPlan (see fl.stream.stream_fl_plan)")
-    from repro.fl.stream import plan_row
+    n_rounds = keys.fault.shape[0]
+    # a traced index clamps where an eager one raises: check the stacks here
+    for x in jax.tree_util.tree_leaves((keys, rows, plan)):
+        if jnp.ndim(x) and jnp.shape(x)[0] < n_rounds:
+            raise ValueError(f"a stacked input holds {jnp.shape(x)[0]} rows "
+                             f"for {n_rounds} rounds")
+
+    # scalar knobs, which every round shares, bypass the input program:
+    # passing arrays through a jitted call unchanged costs the host more
+    # than the selection itself
+    stacked = jax.tree_util.tree_map(
+        lambda x: x if jnp.ndim(x) else None, rows)
 
     out = []
-    for t in range(keys.fault.shape[0]):
+    for t in range(n_rounds):
         with jax.profiler.TraceAnnotation(SPAN_INPUTS):
-            args = (round_keys(keys, t), _row_t(rows, t))
-            if plan is not None:
-                args += (plan_row(plan, t),)
+            keys_t, row_t, *plan_t = _round_inputs(keys, stacked, plan,
+                                                   np.int32(t))
+            row_t = jax.tree_util.tree_map(
+                lambda r, x: x if r is None else r, row_t, rows,
+                is_leaf=lambda r: r is None)
         with jax.profiler.TraceAnnotation(SPAN_ENQUEUE):
-            state, m = step(state, *args)
+            state, m = step(state, keys_t, row_t, *plan_t)
         if not overlap:
             state = jax.block_until_ready(state)
             m = jax.block_until_ready(m)
         out.append(m)
     with jax.profiler.TraceAnnotation(SPAN_STACK):
-        return state, {k: jnp.stack([m[k] for m in out]) for k in out[0]}
+        return state, _stack_rounds(out)
 
 
 def stack_metrics(metrics) -> dict:
     """Materialize a :func:`serve_rounds` metrics dict on the host."""
-    import numpy as np
-
     return {k: np.asarray(v) for k, v in metrics.items()}

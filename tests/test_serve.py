@@ -634,6 +634,121 @@ def test_fl_verify_gate_rejects_poisoned_bs():
 
 
 # ---------------------------------------------------------------------------
+# the host loop's compiled input selection and metric stack
+# ---------------------------------------------------------------------------
+
+
+def _loop_case(fl: bool, rounds: int):
+    """A fresh 16-twin churning stream: ``(cfg, scfg, state, keys, rows,
+    plan)``. With ``fl`` the tiny model streams (scalar knob row, a plan);
+    without, faults and migration run on a knob stack consumed one row per
+    round, and there is no plan."""
+    if fl:
+        from repro.fl import stream as fls
+
+        fcfg = fls.FLServeConfig(model="tiny", participants=4,
+                                 local_iters=1, batch_size=8, verify=False)
+        cfg, scfg, state, row, plan, keys = _fl_tiny_setup(
+            fcfg, 16, 3, rounds, join=0.2, leave=0.2, n_live=12,
+            max_train=500)
+        return cfg, scfg, state, keys, row, plan
+    cfg = EnvConfig(n_twins=16, n_bs=3, faults=FaultConfig(0.3, 0.2, 0.25),
+                    migration=MigrationConfig(0.4, 1.5, 0.8))
+    scfg = serve.ServeConfig(capacity=16, join_rate=0.2, leave_rate=0.2)
+    batch = _batch(rounds, straggler=(0.1, 0.4), outage=(0.05, 0.3))
+    rows = scenario.stream_knobs(batch, fcfg=cfg.faults)
+    state = serve.serve_init(cfg, scfg, batch.key[0],
+                             scenario.knob_row(rows, 0), n_live=12)
+    keys = serve.stream_keys(batch.key[0], rounds)
+    return cfg, scfg, state, keys, rows, None
+
+
+@pytest.mark.parametrize("overlap", [True, False])
+@pytest.mark.parametrize("fl", [True, False], ids=["plan", "no_plan"])
+def test_serve_rounds_matches_eager_hand_loop(fl, overlap):
+    """``serve_rounds``' compiled input selection and metric stack give,
+    bit for bit, the final state and metrics of a hand loop that feeds the
+    same step eagerly sliced ``round_keys`` / ``_row_t`` / ``plan_row``
+    rows."""
+    from repro.fl import stream as fls
+
+    rounds = 3
+    cfg, scfg, state, keys, rows, plan = _loop_case(fl, rounds)
+    step = serve.make_round_step(cfg, scfg)
+    got_state, got = serve.serve_rounds(cfg, scfg, state, keys, rows,
+                                        step=step, overlap=overlap,
+                                        plan=plan)
+    got = serve.stack_metrics(got)
+
+    *_, state, keys, rows, plan = _loop_case(fl, rounds)
+    out = []
+    for t in range(rounds):
+        args = (serve.round_keys(keys, t), serve._row_t(rows, t))
+        if plan is not None:
+            args += (fls.plan_row(plan, t),)
+        state, m = step(state, *args)
+        out.append({k: np.asarray(v) for k, v in m.items()})
+
+    assert got.keys() == out[0].keys()
+    for k in got:
+        np.testing.assert_array_equal(got[k], np.stack([m[k] for m in out]))
+    got_leaves, got_tree = jax.tree_util.tree_flatten(got_state)
+    want_leaves, want_tree = jax.tree_util.tree_flatten(state)
+    assert got_tree == want_tree
+    for a, b in zip(got_leaves, want_leaves):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_serve_rounds_rejects_short_stacks():
+    """A knob stack or plan with fewer rows than the call's rounds is
+    refused before any dispatch (a traced index would clamp silently)."""
+    cfg, scfg, state, keys, rows, _ = _loop_case(False, 3)
+    short = jax.tree_util.tree_map(lambda x: x[:2], rows)
+    with pytest.raises(ValueError, match="2 rows for 3 rounds"):
+        serve.serve_rounds(cfg, scfg, state, keys, short)
+    assert not state.active.is_deleted()
+
+
+def test_serve_rounds_steady_stream_compiles_nothing():
+    """The input program compiles once for a 1-round and once for a
+    3-round call (the round index is traced, not static), the metric stack
+    once per ``n_rounds``; two more 3-round calls compile nothing."""
+    from repro.fl import stream as fls
+
+    cfg, scfg, state, keys, row, plan = _loop_case(True, 3)
+    step = serve.make_round_step(cfg, scfg)
+    keys1 = jax.tree_util.tree_map(lambda x: x[:1], keys)
+    plan1 = fls.FLPlan(*(x[:1] for x in plan))
+    jax.block_until_ready((keys1, plan1))
+    serve._round_inputs.clear_cache()
+    serve._stack_rounds.clear_cache()
+
+    compiles = []
+
+    def listen(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        state, m = serve.serve_rounds(cfg, scfg, state, keys1, row,
+                                      step=step, plan=plan1)
+        state, m = serve.serve_rounds(cfg, scfg, state, keys, row,
+                                      step=step, plan=plan)
+        jax.block_until_ready((state, m))
+        assert compiles.count("jit(_round_inputs)") == 2, compiles
+        assert compiles.count("jit(_stack_rounds)") == 2, compiles
+        del compiles[:]
+        for _ in range(2):
+            state, m = serve.serve_rounds(cfg, scfg, state, keys, row,
+                                          step=step, plan=plan)
+        jax.block_until_ready((state, m))
+        assert compiles == []
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+
+
+# ---------------------------------------------------------------------------
 # slow battery: 8-device subprocess gate + churn soak
 # ---------------------------------------------------------------------------
 
