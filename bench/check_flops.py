@@ -3,12 +3,13 @@
 
     JAX_PLATFORMS=cpu python3 bench/check_flops.py
 
-For each model of ``bench/configs``, compiles one forward pass, and one
-gradient of the loss, at batch 4 on the CPU and compares
-``models/<model>.forward_flops`` and ``train_flops`` with the FLOPs of XLA's
-``cost_analysis()``. XLA also counts bias adds, ReLUs, pooling and the
-softmax, so its count sits a little above ours; the script prints both and
-fails when they differ by more than 2%.
+For each model of ``bench/configs``, compiles one forward pass (the loss,
+for a model that gives no ``forward``), and one gradient of the loss with
+respect to the trained tree, at batch 4 on the CPU and compares
+``models/<model>.forward_flops`` and ``train_flops`` (per example) with the
+FLOPs of XLA's ``cost_analysis()``. XLA also counts bias adds, ReLUs,
+pooling and the softmax, so its count sits a little above ours; the script
+prints both and fails when they differ by more than 2%.
 """
 import glob
 import json
@@ -25,42 +26,49 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def main():
-    import jax
-    import jax.numpy as jnp
-
     from world import model_module
 
     bad = 0
-    seen = set()
+    seen = {None}
     for path in sorted(glob.glob(os.path.join(HERE, "configs", "*.json"))):
         with open(path) as f:
             cfg = json.load(f)
         if cfg["model"] in seen:
             continue
         seen.add(cfg["model"])
-        mod = model_module(cfg)
-        params = mod.init(jax.random.PRNGKey(0), cfg["param_shapes"])
-        x = jnp.zeros((BATCH,) + mod.IMAGE, jnp.float32)
-        fwd = jax.jit(lambda p, x: mod.forward(p, x, precision=jax.lax.Precision.HIGHEST,
-                                               dtype=jnp.float32))
-        y = jnp.zeros((BATCH,), jnp.int32)
-
-        def loss(p, x, y):
-            logits = mod.forward(p, x, precision=jax.lax.Precision.HIGHEST, dtype=jnp.float32)
-            return -jnp.mean(jnp.take_along_axis(jax.nn.log_softmax(logits), y[:, None], 1))
-
-        for what, fn, args, ours in (
-                ("forward", fwd, (params, x), mod.forward_flops(cfg["param_shapes"])),
-                ("train", jax.jit(jax.grad(loss)), (params, x, y),
-                 mod.train_flops(cfg["param_shapes"]))):
-            cost = fn.lower(*args).compile().cost_analysis()
-            cost = cost[0] if isinstance(cost, (list, tuple)) else cost
-            xla = float(cost["flops"]) / BATCH
-            rel = (xla - ours) / ours
-            print(f"{cfg['model']} {what} per image: ours {ours:.6g}, XLA cost_analysis {xla:.6g}, "
-                  f"XLA above ours by {100 * rel:.3f}%")
-            bad += abs(rel) > TOLERANCE
+        bad += check(model_module(cfg), cfg)
     return 1 if bad else 0
+
+
+def check(mod, cfg) -> int:
+    """1 where ``mod``'s FLOPs per example miss XLA's count by more than
+    ``TOLERANCE``, else 0; prints both."""
+    import jax
+    import jax.numpy as jnp
+
+    from reference import model_fns
+
+    highest = jax.lax.Precision.HIGHEST
+    key = jax.random.PRNGKey(0)
+    params = mod.init(key, cfg)
+    frozen = mod.init_frozen(key, cfg) if hasattr(mod, "init_frozen") else None
+    x, y, _, _ = mod.make_data(key, key, dict(cfg, n_train=BATCH, n_test=BATCH))
+    loss, _ = model_fns(mod, frozen, jnp.float32, highest)
+    if hasattr(mod, "forward"):
+        fwd = (lambda p, x, y: mod.forward(p, x, precision=highest, dtype=jnp.float32))
+    else:
+        fwd = loss
+    bad = 0
+    for what, fn, ours in (("forward", jax.jit(fwd), mod.forward_flops(cfg)),
+                           ("train", jax.jit(jax.grad(loss)), mod.train_flops(cfg))):
+        cost = fn.lower(params, x, y).compile().cost_analysis()
+        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+        xla = float(cost["flops"]) / BATCH
+        rel = (xla - ours) / ours
+        print(f"{cfg['model']} {what} per example: ours {ours:.6g}, XLA cost_analysis "
+              f"{xla:.6g}, XLA above ours by {100 * rel:.3f}%")
+        bad += abs(rel) > TOLERANCE
+    return int(bad > 0)
 
 
 if __name__ == "__main__":

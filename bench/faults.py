@@ -15,11 +15,19 @@ is broken in one way a later change could break it:
 * ``altered``           - an answer altered where it is produced: the round
                           time is returned 0.1% high.
 
-A cell on one chip has no exchange between chips to leave out.
+A configuration without FL has no participants or minibatches, so only
+``stale_state`` and ``altered`` apply to it (``kinds``). A cell on one chip
+has no exchange between chips to leave out.
 """
 from service import Service
 
 KINDS = ("stale_state", "half_participants", "half_minibatch", "altered")
+FL_KINDS = ("half_participants", "half_minibatch")
+
+
+def kinds(cfg):
+    """The faults that a cell of configuration ``cfg`` can have."""
+    return tuple(k for k in KINDS if cfg["model"] is not None or k not in FL_KINDS)
 
 
 def faulty(kind):

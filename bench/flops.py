@@ -1,12 +1,11 @@
 """Operations and bytes, computed from shapes.
 
 * ``round_model_flops(cfg, traffic)`` - the FL model FLOPs one served round
-  needs: local SGD (the model's ``train_flops`` per image) over
-  participants x local iterations x batch, the verify gate's forward over
-  every BS aggregate on the holdout slice (where the configuration has the
-  gate), and one forward of the new global
-  model on it (the accuracy's second forward is recomputation and does not
-  count).
+  needs: local SGD (the model's ``train_flops`` per example: an image, or a
+  sequence) over participants x local iterations x batch, the verify
+  gate's forward over every BS aggregate on the holdout slice (where the
+  configuration has the gate), and one forward of the new global model on
+  it (the accuracy's second forward is recomputation and does not count).
 * ``custom_calls(hlo_text)`` - every ``tpu_custom_call`` of a compiled HLO
   module with its operand and result shapes, and the bytes and operations
   the segment reduction needs for it: each operand read once, each result
@@ -28,8 +27,7 @@ _CALL = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*?)\s+custom-call\(.*"
 
 def round_model_flops(cfg, traffic) -> float:
     mod = model_module(cfg)
-    f = mod.forward_flops(cfg["param_shapes"])
-    t = mod.train_flops(cfg["param_shapes"])
+    f, t = mod.forward_flops(cfg), mod.train_flops(cfg)
     train = traffic["participants"] * cfg["local_iters"] * cfg["batch_size"] * t
     evals = (cfg["n_bs"] if cfg["verify"] else 0) * cfg["n_eval"] * f + cfg["n_eval"] * f
     return float(train + evals)

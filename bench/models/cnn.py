@@ -4,18 +4,19 @@ ReLU layer and a 10-way linear head, on 32x32x3 images (NHWC, HWIO).
 
 ``forward`` takes the operand dtype and matmul precision, so the same code is
 the float32 reference (at the configured precision) and its low-precision
-control.
+control. Its data set is ``images.py``'s.
 """
 import jax
 import jax.numpy as jnp
 
-IMAGE = (32, 32, 3)
+from models.images import DATA_SET, IMAGE, make_data  # noqa: F401
 
 
-def init(key, shapes, dtype=jnp.float32):
-    """He-normal weights and zero biases, one key per weight leaf."""
+def init(key, cfg, dtype=jnp.float32):
+    """He-normal weights and zero biases of ``cfg["param_shapes"]``, one key
+    per weight leaf."""
     out = {}
-    for i, (name, shape) in enumerate(sorted(shapes.items())):
+    for i, (name, shape) in enumerate(sorted(cfg["param_shapes"].items())):
         shape = tuple(shape)
         if name.endswith("_b"):
             out[name] = jnp.zeros(shape, dtype)
@@ -59,12 +60,13 @@ def _taps(size, k):
     return sum(min(size - 1, i - lo + k - 1) - max(0, i - lo) + 1 for i in range(size))
 
 
-def forward_flops(shapes) -> int:
+def forward_flops(cfg) -> int:
     """Multiply-add FLOPs (2 per MAC) of one image's forward pass: the two
     'SAME' convolutions at 32x32 and (after the first pool) 16x16, counting
     only the taps that land inside the image (a tap on the zero padding is
     no work the model needs), and the two dense layers. Biases, ReLU and
     pooling are not counted."""
+    shapes = cfg["param_shapes"]
     h, w, _ = IMAGE
     kh, kw, cin, c1 = shapes["conv1_w"]
     conv1 = 2 * _taps(h, kh) * _taps(w, kw) * cin * c1
@@ -75,10 +77,10 @@ def forward_flops(shapes) -> int:
     return conv1 + conv2 + 2 * fin * f1 + 2 * fin2 * f2
 
 
-def train_flops(shapes) -> int:
+def train_flops(cfg) -> int:
     """FLOPs of one image's forward and backward pass: the backward pass
     takes two of each layer's forward FLOPs (input and weight gradients),
     except the first convolution, whose input gradient nothing needs."""
-    kh, kw, cin, c1 = shapes["conv1_w"]
+    kh, kw, cin, c1 = cfg["param_shapes"]["conv1_w"]
     conv1 = 2 * _taps(IMAGE[0], kh) * _taps(IMAGE[1], kw) * cin * c1
-    return 3 * forward_flops(shapes) - conv1
+    return 3 * forward_flops(cfg) - conv1

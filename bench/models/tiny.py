@@ -4,19 +4,21 @@ linear head (3,258 parameters).
 
 ``forward`` takes the operand dtype and matmul precision, so the same code is
 the float32 reference (at the configured precision) and its low-precision
-control.
+control. Its data set is ``images.py``'s.
 """
 import jax
 import jax.numpy as jnp
 
-IMAGE = (32, 32, 3)
+from models.images import DATA_SET, IMAGE, make_data  # noqa: F401
+
 POOL = 4
 
 
-def init(key, shapes, dtype=jnp.float32):
-    """He-normal weights and zero biases, one key per weight leaf."""
+def init(key, cfg, dtype=jnp.float32):
+    """He-normal weights and zero biases of ``cfg["param_shapes"]``, one key
+    per weight leaf."""
     out = {}
-    for i, (name, shape) in enumerate(sorted(shapes.items())):
+    for i, (name, shape) in enumerate(sorted(cfg["param_shapes"].items())):
         shape = tuple(shape)
         if name.startswith("b"):
             out[name] = jnp.zeros(shape, dtype)
@@ -38,21 +40,21 @@ def forward(params, images, *, precision, dtype):
                    preferred_element_type=dtype) + p["b2"]
 
 
-def forward_flops(shapes) -> int:
+def forward_flops(cfg) -> int:
     """FLOPs of one image's forward pass: one add per input element for the
     mean pooling, and 2 per MAC of the two dense layers. Biases and ReLU are
     not counted."""
     h, w, c = IMAGE
-    fin, f1 = shapes["w1"]
-    fin2, f2 = shapes["w2"]
+    fin, f1 = cfg["param_shapes"]["w1"]
+    fin2, f2 = cfg["param_shapes"]["w2"]
     return h * w * c + 2 * fin * f1 + 2 * fin2 * f2
 
 
-def train_flops(shapes) -> int:
+def train_flops(cfg) -> int:
     """FLOPs of one image's forward and backward pass: the backward pass
     takes the second layer's input and weight gradients and the first
     layer's weight gradient; nothing needs the first layer's input gradient
     or the pooling's."""
-    fin, f1 = shapes["w1"]
-    fin2, f2 = shapes["w2"]
-    return forward_flops(shapes) + 2 * (2 * fin2 * f2) + 2 * fin * f1
+    fin, f1 = cfg["param_shapes"]["w1"]
+    fin2, f2 = cfg["param_shapes"]["w2"]
+    return forward_flops(cfg) + 2 * (2 * fin2 * f2) + 2 * fin * f1

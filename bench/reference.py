@@ -18,21 +18,26 @@ paper and the service's documentation state it:
    fixed Eq. 16 term without);
 4. the chain round, where configured: per-BS submission losses, the
    median-plus-tolerance verdict, stakes and verdict history;
-5. the FL round: every participant runs ``local_iters`` steps of SGD (with
-   the configured momentum) from the global model, Eq. 4 takes each BS's
-   data-weighted mean of its participants, the verify gate (where
-   configured) accepts BSs whose holdout loss is at most the median plus
-   the tolerance, and Eq. 5 takes the plain mean of the accepted BSs (the
-   old model stays when none is accepted);
+5. the FL round, where the configuration trains a model: every participant
+   runs ``local_iters`` steps of SGD (with the configured momentum) from the
+   global model, Eq. 4 takes each BS's data-weighted mean of its
+   participants, the verify gate (where configured) accepts BSs whose
+   holdout loss is at most the median plus the tolerance, and Eq. 5 takes
+   the plain mean of the accepted BSs (the old model stays when none is
+   accepted). A model's frozen tree (``init_frozen``) is a constant of the
+   loss: it is neither trained nor aggregated;
 6. churn: Bernoulli departures and admissions, admitted twins drawing a
    population size and a uniform association.
 
-Two decisions compare a float with a line: the association's argmax and the
-verify gate. Where the reference's own margin to the line is under the
-configured margin (``margins``), lower precision may decide either way; there
-the reference follows the decision of what it is compared with (``follow``)
-and the rounds go on from the same decisions. Where the margin is wider, it
-decides alone, and a different decision of the other side is counted.
+Three decisions compare a float with a line: the association's argmax, the
+verify gate and a moving twin's destination. Where the reference's own
+margin to the line is under the configured margin (``margins``), lower
+precision may decide either way; there the reference follows the decision of
+what it is compared with (``follow``) and the rounds go on from the same
+decisions. Where the margin is wider, it decides alone, and a different
+decision of the other side is counted. A destination is followed by
+``follow["mig_alt"]``, which sends the twin to its runner-up BS; the round
+reports each mover's margin (``mig_gap``) for the caller to choose it.
 
 Per-BS sums are plain one-hot contractions, exact in float32 (``HIGHEST``).
 The models' and the actors' matmuls run at the precision the configuration
@@ -43,6 +48,7 @@ precision everywhere. Random draws are float32 in both, as they are drawn
 from the same keys.
 """
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
@@ -172,17 +178,35 @@ def _top_two_gap(scores):
     return s[-1] - s[-2]
 
 
-def _fl(cfg, model, params, plan, active, data, assoc, x, y, x_eval, y_eval,
-        follow_accepts, margin, dt, precision, mp):
-    """One FL round over the plan's participants. Returns the new global
-    model and the round's FL answers. ``follow_accepts`` (or None) is the
-    other side's number of accepted BSs; ``margin`` the verify gate's."""
-    m = cfg["n_bs"]
+def model_fns(model, frozen, dt, mp):
+    """``(loss, accuracy)`` of ``model``, each ``f(params, xs, ys)``, in
+    ``dt`` at matmul precision ``mp``: the module's own, given ``frozen``,
+    where it has them; else softmax cross-entropy and argmax accuracy over
+    its ``forward``'s logits, one label per example."""
+    if hasattr(model, "loss"):
+        kw = {"precision": mp, "dtype": dt}
+        return (lambda p, xs, ys: model.loss(p, frozen, xs, ys, **kw),
+                lambda p, xs, ys: model.accuracy(p, frozen, xs, ys, **kw))
     fwd = functools.partial(model.forward, precision=mp, dtype=dt)
 
-    def loss_fn(p, xs, ys):
+    def loss(p, xs, ys):
         logp = jax.nn.log_softmax(fwd(p, xs).astype(jnp.float32), axis=-1)
         return -jnp.mean(jnp.take_along_axis(logp, ys[:, None], axis=-1))
+
+    def accuracy(p, xs, ys):
+        return jnp.mean(jnp.argmax(fwd(p, xs).astype(jnp.float32), -1) == ys)
+
+    return loss, accuracy
+
+
+def _fl(cfg, model, params, frozen, plan, active, data, assoc, x, y, x_eval, y_eval,
+        follow_accepts, margin, dt, precision, mp):
+    """One FL round over the plan's participants. Returns the new global
+    model and the round's FL answers. ``frozen`` (or None) is the model's
+    frozen tree, in ``dt``; ``follow_accepts`` (or None) the other side's
+    number of accepted BSs; ``margin`` the verify gate's."""
+    m = cfg["n_bs"]
+    loss_fn, accuracy_fn = model_fns(model, frozen, dt, mp)
 
     users = plan["users"]
     part = plan["valid"] & active[users]
@@ -243,10 +267,9 @@ def _fl(cfg, model, params, plan, active, data, assoc, x, y, x_eval, y_eval,
         per_bs)
     new = jax.tree_util.tree_map(lambda old, a: jnp.where(jnp.any(accept), a, old),
                                  p0, agg)
-    logits = fwd(new, x_eval).astype(jnp.float32)
     answers = {
         "fl_loss": loss_fn(new, x_eval, y_eval),
-        "fl_accuracy": jnp.mean(jnp.argmax(logits, -1) == y_eval),
+        "fl_accuracy": accuracy_fn(new, x_eval, y_eval),
         "fl_bs_weight": bs_w.astype(jnp.float32),
         "fl_n_participants": jnp.sum(part.astype(jnp.int32)),
         "fl_accept_frac": (jnp.sum(accept.astype(jnp.float32))
@@ -290,6 +313,7 @@ def _round(cfg, traffic, model, consts, st, keys, plan, follow, margins, dt, pre
         tau = jnp.full(consts["h_up"].shape, 1.0 / m, dt)
     up, down = _rates(cfg, tau, consts["h_up"], consts["h_down"], consts["dist"], dt)
     assoc = assoc_cmd
+    step_answers = {}
     if mig:
         loads = _per_bs(data.astype(dt), assoc_cmd, m, precision)
         pen = loads / jnp.maximum(jnp.mean(loads), 1e-12)
@@ -300,8 +324,16 @@ def _round(cfg, traffic, model, consts, st, keys, plan, follow, margins, dt, pre
                   - mig["load_weight"] * pen[None, :])
         k_move, k_dst = jax.random.split(keys["mig"])
         move = jax.random.uniform(k_move, (n,)) < mig["p_move"]
-        choice = jnp.argmax(logits + jax.random.gumbel(k_dst, (n, m)).astype(dt), axis=1)
+        z = logits + jax.random.gumbel(k_dst, (n, m)).astype(dt)
+        choice = jnp.argmax(z, axis=1)
+        top, top_bs = jax.lax.top_k(z, 2)
+        if follow is not None and "mig_alt" in follow:
+            choice = jnp.where(follow["mig_alt"], top_bs[:, 1], choice)
         assoc = jnp.where(active, jnp.where(move, choice, assoc_cmd), m).astype(jnp.int32)
+        step_answers["migration_rate"] = (jnp.sum((assoc != assoc_cmd).astype(jnp.float32))
+                                          / n)
+        step_answers["mig_gap"] = jnp.where(active & move, top[:, 0] - top[:, 1],
+                                            jnp.inf).astype(jnp.float32)
 
     # 2. faults
     bad = st["bad"]
@@ -310,13 +342,16 @@ def _round(cfg, traffic, model, consts, st, keys, plan, follow, margins, dt, pre
         k_mask, k_mag = jax.random.split(k_slow)
         is_slow = jax.random.uniform(k_mask, (n,)) < knobs["straggler"]
         extra = jax.random.exponential(k_mag, (n,)) * flt["straggler_slowdown"]
-        b = b * (1.0 + jnp.where(is_slow, extra, 0.0)).astype(dt)
+        slow = 1.0 + jnp.where(is_slow, extra, 0.0)
+        b = b * slow.astype(dt)
         pi_b = jnp.clip(knobs["outage"], 0.0, 0.95)
         p_bg = 1.0 / max(flt["burst_len"], 1.0)
         p_gb = jnp.clip(pi_b * p_bg / (1.0 - pi_b), 0.0, 1.0)
         u = jax.random.uniform(k_out, (m,))
         bad = jnp.where(st["bad"], u >= p_bg, u < p_gb)
         up = jnp.where(bad, up * flt["outage_floor"], up)
+        step_answers["straggler_frac"] = jnp.mean((slow > 1.0).astype(jnp.float32))
+        step_answers["outage_frac"] = jnp.mean(bad.astype(jnp.float32))
 
     # 3. Eq. 17
     t_cmp = _per_bs(b * data.astype(dt), assoc, m, precision) * lat["cycles_per_sample"] / freqs
@@ -346,10 +381,14 @@ def _round(cfg, traffic, model, consts, st, keys, plan, follow, margins, dt, pre
             / jnp.maximum(jnp.sum(submitted.astype(jnp.float32)), 1.0))
 
     # 5. FL round on the pre-churn population and the migrated association
-    params, fl = _fl(cfg, model, st["params"], plan, active, data, assoc,
-                     consts["x"], consts["y"], consts["x_eval"], consts["y_eval"],
-                     None if follow is None else follow["accepts"], margins["verify"],
-                     dt, precision, mp)
+    params, fl = st["params"], {}
+    if model is not None:
+        frozen = (jax.tree_util.tree_map(lambda v: v.astype(dt), consts["frozen"])
+                  if "frozen" in consts else None)
+        params, fl = _fl(cfg, model, params, frozen, plan, active, data, assoc,
+                         consts["x"], consts["y"], consts["x_eval"], consts["y_eval"],
+                         None if follow is None else follow["accepts"],
+                         margins["verify"], dt, precision, mp)
 
     # 6. churn
     n_joined = n_left = jnp.int32(0)
@@ -367,7 +406,7 @@ def _round(cfg, traffic, model, consts, st, keys, plan, follow, margins, dt, pre
         n_joined = jnp.sum(join.astype(jnp.int32))
         n_left = jnp.sum(leave.astype(jnp.int32))
 
-    answers = dict(fl, **chain_answers)
+    answers = dict(fl, **chain_answers, **step_answers)
     answers.update({"round_time": round_time.astype(jnp.float32),
                     "n_active": jnp.sum(active.astype(jnp.int32)),
                     "n_joined": n_joined, "n_left": n_left,
@@ -393,14 +432,15 @@ def initial(cfg, world):
                  "rewards": jnp.zeros((cc["history"], m), jnp.float32),
                  "round": jnp.zeros((), jnp.int32)}
     st = {"active": jnp.ones((n,), bool), "data": data, "assoc": assoc,
-          "bad": world["bad"], "chain": chain, "params": world["params"]}
+          "bad": world["bad"], "chain": chain, "params": world.get("params")}
     consts = {"knobs": world["knobs"], "h_up": world["h_up"], "h_down": world["h_down"],
-              "dist": world["dist"], "byz": world["byz"],
-              "freqs": freqs_hz(cfg), "x": world["x"], "y": world["y"],
-              "x_eval": world["x_test"][:cfg["n_eval"]],
-              "y_eval": world["y_test"][:cfg["n_eval"]]}
-    if "actor" in world:
-        consts["actor"] = world["actor"]
+              "dist": world["dist"], "byz": world["byz"], "freqs": freqs_hz(cfg)}
+    if "params" in world:
+        consts.update(x=world["x"], y=world["y"], x_eval=world["x_test"][:cfg["n_eval"]],
+                      y_eval=world["y_test"][:cfg["n_eval"]])
+    for k in ("actor", "frozen"):
+        if k in world:
+            consts[k] = world[k]
     return st, consts
 
 
@@ -408,11 +448,26 @@ def run(cfg, traffic, world, rounds, n_rounds, margins, *, follow=None, control=
     """Follow the first ``n_rounds`` rounds. ``rounds`` is
     ``traffic.rounds(...)`` over them; ``follow`` (or None) the other side's
     decisions, {"assoc": (rounds, N), "accepts": (rounds,)}, taken where the
-    reference's own margin is under ``margins``. Returns the per-round
+    reference's own margin is under ``margins``, and {"mig_alt": (rounds, N)}
+    the destinations it takes the runner-up for. Returns the per-round
     answers (host arrays on a leading round axis) and the global model after
-    each round. ``control`` computes in bfloat16 at default matmul
-    precision; otherwise the models run at the configured precision and the
-    sums at ``HIGHEST``."""
+    each round (None without FL). ``control`` computes in bfloat16 at
+    default matmul precision; otherwise the models run at the configured
+    precision and the sums at ``HIGHEST``."""
+    fn = _runner(json.dumps(cfg, sort_keys=True), json.dumps(traffic, sort_keys=True),
+                 n_rounds, json.dumps(margins, sort_keys=True), control)
+    with jax.default_matmul_precision("default" if control else cfg["matmul_precision"]):
+        answers, globals_ = fn(world, rounds, follow)
+    return (jax.tree_util.tree_map(np.asarray, answers),
+            [jax.tree_util.tree_map(np.asarray, g) for g in globals_])
+
+
+@functools.lru_cache(maxsize=None)
+def _runner(cfg_json, traffic_json, n_rounds, margins_json, control):
+    """``run``'s jitted rounds, one per configuration, mix, length and
+    precision, so that following other decisions traces nothing anew."""
+    cfg, traffic, margins = (json.loads(cfg_json), json.loads(traffic_json),
+                             json.loads(margins_json))
     dt = jnp.bfloat16 if control else jnp.float32
     precision = DEFAULT if control else HIGHEST
     mp = DEFAULT if control else _PRECISION[cfg["matmul_precision"]]
@@ -425,7 +480,7 @@ def run(cfg, traffic, world, rounds, n_rounds, margins, *, follow=None, control=
         out, globals_ = [], []
         for r in range(n_rounds):
             k = {s: v[r] for s, v in keys.items()}
-            p = {s: v[r] for s, v in plans.items()}
+            p = None if plans is None else {s: v[r] for s, v in plans.items()}
             f = None if follow is None else {s: v[r] for s, v in follow.items()}
             st, ans = _round(cfg, traffic, model, consts, st, k, p, f, margins, dt,
                              precision, mp)
@@ -434,7 +489,4 @@ def run(cfg, traffic, world, rounds, n_rounds, margins, *, follow=None, control=
                                                    st["params"]))
         return {k: jnp.stack([a[k] for a in out]) for k in out[0]}, globals_
 
-    with jax.default_matmul_precision("default" if control else cfg["matmul_precision"]):
-        answers, globals_ = go(world, rounds, follow)
-    return (jax.tree_util.tree_map(np.asarray, answers),
-            [jax.tree_util.tree_map(np.asarray, g) for g in globals_])
+    return go

@@ -138,16 +138,18 @@ def checked_rounds(svc, cfg, traffic, seed, gen):
     import world as world_mod
 
     world = world_mod.make_world(cfg, traffic, seed)
-    start = jax.tree_util.tree_map(lambda v: np.asarray(v, np.float64), world["params"])
+    fl = "params" in world
+    start = (jax.tree_util.tree_map(lambda v: np.asarray(v, np.float64), world["params"])
+             if fl else None)
     state = svc.fresh_state(world)
     del world
     policy = cfg["association"] != "average"
     answers, globals_, assoc = [], [], []
-    for _ in range(-(-compare.CHECK_ROUNDS // traffic["rounds_per_call"])):
+    for _ in range(compare.check_calls(traffic["rounds_per_call"])):
         keys, plan = gen.next()
         state, m = svc.call(state, keys, plan)
         answers.append(svc.materialize(m))
-        globals_.append(svc.global_params(state))
+        globals_.append(svc.global_params(state) if fl else None)
         if policy:   # one round a call (service.py)
             assoc.append(svc.association(state))
     jax.block_until_ready(state)
@@ -157,21 +159,54 @@ def checked_rounds(svc, cfg, traffic, seed, gen):
                    "decisions": compare.decisions(answers, assoc if policy else None)}
 
 
-def reference_answers(cfg, traffic, seed, margins, *, follow=None, control=False):
-    """The plain reference over the checked rounds, from the same seed,
-    following ``follow``'s near-line decisions (``reference.run``)."""
+# at most this many open destinations are tried against the other side
+MAX_OPEN_DESTINATIONS = 16
+
+
+def reference_answers(cfg, traffic, seed, margins, *, follow=None, control=False,
+                      against=None):
+    """The plain reference over the checked calls, from the same seed,
+    following ``follow``'s near-line decisions (``reference.run``). With
+    migration on and ``against`` (the other side's answers), each mover
+    whose destination lies within ``margins["migration"]`` of its runner-up
+    is tried at the runner-up, earliest first, and kept there where that
+    brings the answers nearer to ``against``'s (``compare.distance``): the
+    other side's destinations are not among its answers."""
+    import numpy as np
+
     import compare
     import reference
     import traffic as traffic_mod
     import world as world_mod
 
     rpc = traffic["rounds_per_call"]
-    n = -(-compare.CHECK_ROUNDS // rpc) * rpc
-    answers, globals_ = reference.run(
-        cfg, traffic, world_mod.make_world(cfg, traffic, seed),
-        traffic_mod.rounds(cfg, traffic, seed, 0, n), n, margins, follow=follow,
-        control=control)
-    return {"answers": answers, "first": globals_[rpc - 1], "last": globals_[-1]}
+    n = compare.check_calls(rpc) * rpc
+    world = world_mod.make_world(cfg, traffic, seed)
+    rounds = traffic_mod.rounds(cfg, traffic, seed, 0, n)
+
+    def go(f):
+        answers, globals_ = reference.run(cfg, traffic, world, rounds, n, margins, follow=f,
+                                          control=control)
+        return {"answers": answers, "first": globals_[rpc - 1], "last": globals_[-1]}
+
+    if against is None or not cfg["migration"]:
+        return go(follow)
+    alt = np.zeros((n, cfg["capacity"]), bool)
+    ref, tried = go(dict(follow or {}, mig_alt=alt)), set()
+    while len(tried) < MAX_OPEN_DESTINATIONS:
+        near = [tuple(i) for i in np.argwhere(ref["answers"]["mig_gap"]
+                                              < margins["migration"])]
+        near = [i for i in near if i not in tried]
+        if not near:
+            break
+        tried.add(near[0])
+        alt2 = alt.copy()
+        alt2[near[0]] = True
+        ref2 = go(dict(follow or {}, mig_alt=alt2))
+        if compare.distance(against, ref2["answers"]) < compare.distance(against,
+                                                                         ref["answers"]):
+            alt, ref = alt2, ref2
+    return ref
 
 
 def control_answers(cfg, traffic, seed, margins):
@@ -182,7 +217,8 @@ def control_answers(cfg, traffic, seed, margins):
     ctl = reference_answers(cfg, traffic, seed, margins, control=True)
     assoc = ctl["answers"]["assoc"] if cfg["association"] != "average" else None
     ref = reference_answers(cfg, traffic, seed, margins,
-                            follow=compare.decisions(ctl["answers"], assoc))
+                            follow=compare.decisions(ctl["answers"], assoc),
+                            against=ctl["answers"])
     return ctl, ref
 
 
@@ -317,7 +353,8 @@ def run_cell(cell, cfg, traffic, limits, metric_specs, *, seed, seconds, trace,
     # the check: free the program's state, follow the checked rounds
     del state
     gen.queue = []
-    ref = reference_answers(cfg, traffic, seed, limits["margins"], follow=prog["decisions"])
+    ref = reference_answers(cfg, traffic, seed, limits["margins"], follow=prog["decisions"],
+                            against=prog["answers"])
     nums = compare.numbers(prog, ref, prog["start"])
     correct, rows = compare.judge(nums, limits)
     correct = correct and failed == 0

@@ -1,11 +1,11 @@
 """The system under test, built from its public pieces for one cell.
 
 The round step is ``repro.core.serve.make_round_step`` (donated), driven by
-``repro.core.serve.serve_rounds``; the FL buffers come from
-``repro.fl.stream.fl_init``. The initial state holds the seed-made data of
-``world.py``, laid out in the program's own state types; the per-call
-inputs are ``traffic.py``'s keys and plans in the program's key and plan
-types. This is the only module of the benchmark that imports the program.
+``repro.core.serve.serve_rounds``; the FL buffers, where the configuration
+trains an FL model, come from ``repro.fl.stream.fl_init``. The initial state
+holds the seed-made data of ``world.py``, laid out in the program's own
+state types; the per-call inputs are ``traffic.py``'s keys and plans in the
+program's key and plan types. This is the only module of the benchmark that imports the program.
 """
 import numpy as np
 
@@ -13,7 +13,7 @@ import numpy as np
 class Service:
     """One configured stream: ``fresh_state(world)`` and ``call(state,
     keys, plan)`` -> ``(state, metrics)``, with device metrics as the
-    program returns them."""
+    program returns them (``plan`` None without FL)."""
 
     def __init__(self, cfg, traffic):
         from repro.core import serve
@@ -52,7 +52,7 @@ class Service:
             migration=opt(MigrationConfig, cfg["migration"]),
             faults=opt(FaultConfig, cfg["faults"]),
             consensus=opt(ConsensusConfig, cfg["consensus"]))
-        self.fl_cfg = FLServeConfig(
+        self.fl_cfg = None if cfg["model"] is None else FLServeConfig(
             model=cfg["model"], participants=traffic["participants"],
             local_iters=cfg["local_iters"], batch_size=cfg["batch_size"],
             lr=cfg["lr"], momentum=cfg["momentum"],
@@ -72,9 +72,9 @@ class Service:
         from repro.core.consensus import ChainState
         from repro.core.marl.env import EnvState
         from repro.core.scenario import StreamKnobs
-        from repro.fl.stream import fl_init
+        from repro.fl import stream
 
-        from world import freqs_hz
+        from world import freqs_hz, model_module
 
         cfg, ccfg = self.cfg, self.cfg["consensus"]
         n, m = cfg["capacity"], cfg["n_bs"]
@@ -92,9 +92,13 @@ class Service:
                        h_down=world["h_down"], dist=world["dist"], assoc=assoc,
                        t=jnp.int32(0), chain=chain)
         active = np.ones((n,), bool)
-        data_set = ((world["x"], world["y"]), (world["x_test"], world["y_test"]),
-                    "cifar10-sim")
-        fl = fl_init(self.fl_cfg, None, data_set, active, params=world["params"])
+        fl = None
+        if self.fl_cfg is not None:
+            data_set = ((world["x"], world["y"]), (world["x_test"], world["y_test"]),
+                        model_module(cfg).DATA_SET)
+            frozen = {"frozen": world["frozen"]} if "frozen" in world else {}
+            fl = stream.fl_init(self.fl_cfg, None, data_set, active,
+                                params=world["params"], **frozen)
         k = world["knobs"]
         self.row = StreamKnobs(
             data_min=k["data_min"], data_max=k["data_max"], skew=k["skew"],
@@ -124,7 +128,7 @@ class Service:
         serve = self._serve
         return serve.serve_rounds(
             self.env_cfg, self.serve_cfg, state, serve.RoundKeys(**keys), self.row,
-            step=self.step, plan=FLPlan(**plan))
+            step=self.step, plan=None if plan is None else FLPlan(**plan))
 
     def materialize(self, metrics):
         """The call's metrics on the host (blocks until they are there)."""
@@ -150,5 +154,5 @@ class Service:
         serve = self._serve
         keys, plan = gen.next()
         rk = serve.round_keys(serve.RoundKeys(**keys), 0)
-        pr = plan_row(FLPlan(**plan), 0)
+        pr = None if plan is None else plan_row(FLPlan(**plan), 0)
         return self.step.lower(state, rk, self.row, pr).compile().as_text()

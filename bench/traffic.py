@@ -6,10 +6,10 @@ generator draws, independently of every other round:
 
 * the round's key streams (migration, faults, chain, churn, dynamics), each
   a raw (2,) uint32 key folded from the seed and ``r``;
-* the FL plan, by the law of the program's ``stream_fl_plan``: ``P``
-  distinct participants drawn uniformly from the capacity (Floyd's
-  algorithm), and for each of
-  them ``local_iters`` minibatches of ``B`` distinct samples drawn uniformly
+* where the configuration trains an FL model, the FL plan, by the law of
+  the program's ``stream_fl_plan``: ``P`` distinct participants drawn
+  uniformly from the capacity (Floyd's algorithm), and for each of them
+  ``local_iters`` minibatches of ``B`` distinct samples drawn uniformly
   from the first ``n_use`` samples of its cyclic shard (twin ``u``'s shard
   starts at ``u * stride`` and wraps round the data set).
 
@@ -55,9 +55,11 @@ def _distinct(key, n, p):
 
 
 def _round(cfg, traffic, key, r):
-    """Global round ``r``'s keys and plan."""
+    """Global round ``r``'s keys and plan (None without FL)."""
     kr = jax.random.fold_in(key, r)
     keys = {s: jax.random.fold_in(kr, _FOLDS[s]) for s in STREAMS}
+    if cfg["model"] is None:
+        return keys, None
     cap, p = cfg["capacity"], traffic["participants"]
     n_it, b = cfg["local_iters"], cfg["batch_size"]
     stride, n_use = plan_shape(cfg)
@@ -72,16 +74,15 @@ def _round(cfg, traffic, key, r):
 
 def _block(cfg, traffic, key, first_round):
     """``block_calls`` calls of ``rounds_per_call`` rounds from global round
-    ``first_round``: a list of (keys, plan) dicts, each leaf led by the
-    call's round axis."""
+    ``first_round``: a list of (keys, plan) dicts (plan None without FL),
+    each leaf led by the call's round axis."""
     rpc, n_calls = traffic["rounds_per_call"], traffic["block_calls"]
     rounds = first_round + jnp.arange(n_calls * rpc)
     keys, plan = jax.vmap(lambda r: _round(cfg, traffic, key, r))(rounds)
     out = []
     for c in range(n_calls):
         sl = slice(c * rpc, (c + 1) * rpc)
-        out.append(({k: v[sl] for k, v in keys.items()},
-                    {k: v[sl] for k, v in plan.items()}))
+        out.append(jax.tree_util.tree_map(lambda v: v[sl], (keys, plan)))
     return out
 
 

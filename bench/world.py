@@ -1,11 +1,11 @@
 """Everything a run starts from, made on the device from ``--seed``.
 
 One jitted call draws the scenario knobs, the twin population, the radio
-realization, the outage and byzantine masks, the global model's weights, the
-association policy's actor weights (where the configuration runs one) and
-the CIFAR-10-sim data set. The program receives these as its initial state;
-the plain reference starts from the same call. Nothing here imports the
-program.
+realization, the outage and byzantine masks, the association policy's actor
+weights (where the configuration runs one) and, where the configuration
+trains an FL model, the model's weights and data set. The program receives
+these as its initial state; the plain reference starts from the same call.
+Nothing here imports the program.
 """
 import functools
 import importlib
@@ -13,10 +13,6 @@ import importlib
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-# fixed, seed-independent class templates of the generated data set, as the
-# program's own CIFAR-10-sim keeps them fixed (repro.data.cifar10._synthetic)
-_TEMPLATE_KEY = 1234
 
 
 def seed_key(seed: int) -> jnp.ndarray:
@@ -26,6 +22,22 @@ def seed_key(seed: int) -> jnp.ndarray:
 
 
 def model_module(cfg):
+    """The FL model's module ``models/<model>.py``, or None where the
+    configuration runs no FL (``"model": null``). The harness reads a model
+    only through these names of it:
+
+    * ``make_data(key_train, key_test, cfg) -> (x, y, x_test, y_test)``;
+    * ``init(key, cfg)`` - the trained tree; optionally ``init_frozen(key,
+      cfg)`` - a tree that is neither trained nor aggregated;
+    * ``forward(params, xs, *, precision, dtype)``, or ``loss(params,
+      frozen, xs, ys, *, precision, dtype)`` and ``accuracy(...)`` alike
+      (without them, softmax cross-entropy over ``forward``'s logits and one
+      label per example);
+    * ``forward_flops(cfg)``, ``train_flops(cfg)`` - FLOPs per example;
+    * ``DATA_SET`` - the data set's name, as the program's FL state takes it.
+    """
+    if cfg["model"] is None:
+        return None
     return importlib.import_module(f"models.{cfg['model']}")
 
 
@@ -70,30 +82,6 @@ def _uniform(key, lo_hi):
     return jax.random.uniform(key, (), minval=lo, maxval=hi)
 
 
-def _images(key, templ_key, n, classes):
-    """Class-conditional Gabor-plus-blob textures with per-image noise and a
-    per-channel shift, clipped to [0, 1] (the law of the program's
-    CIFAR-10-sim, drawn on the device)."""
-    kt = jax.random.split(templ_key, 4)
-    angles = jax.random.uniform(kt[0], (classes,), minval=0.0, maxval=np.pi)
-    freqs = jax.random.uniform(kt[1], (classes,), minval=3.0, maxval=9.0)
-    colors = jax.random.uniform(kt[2], (classes, 3), minval=0.2, maxval=1.0)
-    centers = jax.random.uniform(kt[3], (classes, 2), minval=0.25, maxval=0.75)
-    yy, xx = jnp.meshgrid(jnp.arange(32, dtype=jnp.float32) / 32.0,
-                          jnp.arange(32, dtype=jnp.float32) / 32.0, indexing="ij")
-    u = (jnp.cos(angles)[:, None, None] * xx + jnp.sin(angles)[:, None, None] * yy)
-    gabor = 0.5 + 0.5 * jnp.sin(2 * np.pi * freqs[:, None, None] * u)
-    blob = jnp.exp(-(((xx - centers[:, 0, None, None]) ** 2
-                      + (yy - centers[:, 1, None, None]) ** 2) / 0.05))
-    base = (0.6 * gabor + 0.4 * blob)[..., None] * colors[:, None, None, :]
-    ky, kj, ks = jax.random.split(key, 3)
-    y = jax.random.randint(ky, (n,), 0, classes).astype(jnp.int32)
-    jitter = 0.25 * jax.random.normal(kj, (n, 32, 32, 3))
-    shift = 0.1 * jax.random.normal(ks, (n, 1, 1, 3))
-    x = jnp.clip(base[y] + jitter + shift, 0.0, 1.0)
-    return x.astype(jnp.float32), y
-
-
 def _make(cfg, traffic, key):
     n, m = cfg["capacity"], cfg["n_bs"]
     c = cfg["wireless"]["n_subchannels"]
@@ -110,14 +98,15 @@ def _make(cfg, traffic, key):
     dist = jax.random.uniform(ks[11], (m,), minval=w["min_dist_m"], maxval=w["max_dist_m"])
     bad = jax.random.uniform(ks[12], (m,)) < jnp.clip(knobs["outage"], 0.0, 0.95)
     byz = jax.random.uniform(ks[13], (m,)) < knobs["byzantine"]
-    params = model_module(cfg).init(ks[14], cfg["param_shapes"])
-    templ = jax.random.PRNGKey(_TEMPLATE_KEY)
-    kd = jax.random.split(ks[15])
-    x, y = _images(kd[0], templ, cfg["n_train"], cfg["n_classes"])
-    x_test, y_test = _images(kd[1], templ, cfg["n_test"], cfg["n_classes"])
     out = {"knobs": knobs, "data_sizes": data.astype(jnp.float32), "h_up": h_up,
-           "h_down": h_down, "dist": dist, "bad": bad, "byz": byz, "params": params,
-           "x": x, "y": y, "x_test": x_test, "y_test": y_test}
+           "h_down": h_down, "dist": dist, "bad": bad, "byz": byz}
+    model = model_module(cfg)
+    if model is not None:
+        out["params"] = model.init(ks[14], cfg)
+        kd = jax.random.split(ks[15])
+        out["x"], out["y"], out["x_test"], out["y_test"] = model.make_data(kd[0], kd[1], cfg)
+        if hasattr(model, "init_frozen"):
+            out["frozen"] = model.init_frozen(jax.random.fold_in(key, 19), cfg)
     if cfg["association"] == "factorized":
         out["actor"] = _actor(jax.random.fold_in(key, 17), cfg)
     return out
